@@ -16,7 +16,7 @@ import os
 import signal
 import sys
 import threading
-from http.server import BaseHTTPRequestHandler, ThreadingHTTPServer
+from http.server import BaseHTTPRequestHandler
 from typing import Any, Dict
 
 import yaml
@@ -26,6 +26,7 @@ from ..cluster import BackgroundScanService, ClusterSnapshot, PolicyCache, Repor
 from ..config import Configuration, Toggles
 from ..observability.metrics import global_registry
 from ..webhooks import AdmissionServer, build_handlers
+from ..webhooks.server import HTTPServer
 
 
 def add_parser(sub: argparse._SubParsersAction) -> None:
@@ -127,13 +128,9 @@ def add_parser(sub: argparse._SubParsersAction) -> None:
                         "ladder (snapshot -> compile -> atomic swap)")
     p.add_argument("--reload-interval", type=float, default=2.0,
                    help="seconds between --policy-watch polls")
-    # performance: persistent XLA compile cache + content-addressed
-    # verdict/encode caches (tpu/cache.py)
-    p.add_argument("--xla-cache-dir", default=None, metavar="DIR",
-                   help="persistent XLA compilation cache directory so "
-                        "compiled device programs survive restarts "
-                        "(default: $KYVERNO_TPU_XLA_CACHE_DIR or "
-                        "./.xla_cache; 'none' disables)")
+    # performance: content-addressed verdict/encode caches
+    # (tpu/cache.py); the persistent XLA compile cache follows
+    # $JAX_COMPILATION_CACHE_DIR, else <repo>/.xla_cache
     p.add_argument("--verdict-cache-size", type=int, default=None,
                    metavar="N",
                    help="verdict-column LRU capacity in entries "
@@ -494,7 +491,7 @@ class ControlPlane:
         self._cleanup_on_shutdown(self.snapshot, self.lease_store)
 
 
-def _metrics_server(cp: "ControlPlane", port: int) -> ThreadingHTTPServer:
+def _metrics_server(cp: "ControlPlane", port: int) -> HTTPServer:
     class _Req(BaseHTTPRequestHandler):
         def log_message(self, *a):
             pass
@@ -566,7 +563,7 @@ def _metrics_server(cp: "ControlPlane", port: int) -> ThreadingHTTPServer:
             else:
                 self._send(404, b"")
 
-    return ThreadingHTTPServer(("127.0.0.1", port), _Req)
+    return HTTPServer(("127.0.0.1", port), _Req)
 
 
 def _init_distributed():
@@ -654,7 +651,7 @@ def run(args: argparse.Namespace) -> int:
         # compile-time knob (tpu/dfa.py approx_error_ceiling())
         os.environ["KYVERNO_TPU_DFA_APPROX_ERROR"] = \
             str(args.dfa_approx_error)
-    xla_dir = enable_xla_compile_cache(args.xla_cache_dir)
+    xla_dir = enable_xla_compile_cache()
     if xla_dir:
         global_oplog.emit("xla_cache_enabled", dir=xla_dir)
     # columnar row store ON by default for serve (in-memory unless
@@ -757,6 +754,13 @@ def run(args: argparse.Namespace) -> int:
         # single-host — the fleet layer above is what carries the
         # process-level story either way.
         mesh = _init_distributed()
+    if toggles.engine == "tpu":
+        # attach (after jax.distributed, before any compile) and say
+        # where batches will run: a device the operator did not expect
+        # must show in the first lines of the log, not only in latency
+        from ..tpu import device_info
+
+        global_oplog.emit("device_attached", **device_info())
     exporter = None
     if args.trace_export:
         from ..observability.tracing import (OTLPJsonFileExporter,

@@ -459,12 +459,18 @@ class BackgroundScanService:
                 # breadcrumb, not just a /metrics curve)
                 if "encode_pool" in pstats:
                     self.stats["encode_pool"] = pstats["encode_pool"]
-            except Exception:
+            except Exception as e:
                 # the pipeline's own ladder (quarantine, breaker,
                 # scalar completion) should have absorbed this — if it
                 # still escapes, unreported chunks get per-rule ERROR
                 # verdicts rather than aborting the whole scan loop
+                from ..observability.log import global_oplog
                 from ..tpu.evaluator import ERROR as _ERR
+
+                global_oplog.emit(
+                    "scan_tick_failed", level="error",
+                    unreported_chunks=len(chunks) - len(reported),
+                    error=f"{type(e).__name__}: {e}"[:2000])
 
                 for idx, chunk_res in enumerate(chunks):
                     if idx in reported:

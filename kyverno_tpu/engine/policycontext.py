@@ -94,3 +94,48 @@ class PolicyContext:
         if self.operation == "DELETE" and not self.new_resource and self.old_resource:
             return self.old_resource
         return self.new_resource
+
+
+def scan_json_context(resource: Dict[str, Any], operation: str = "",
+                      admission_info: Optional[RequestInfo] = None
+                      ) -> Context:
+    """The JSON context both engines evaluate against for one scanned
+    resource: request.object/namespace/operation/userInfo + images.*
+    (policy_context.go:257)."""
+    ctx = Context()
+    ctx.add_resource(resource)
+    ns = (resource.get("metadata") or {}).get("namespace", "")
+    if ns:
+        ctx.add_namespace(ns)
+    if operation:
+        ctx.add_operation(operation)
+    info = admission_info or RequestInfo()
+    ctx.add_user_info({"username": info.username, "uid": info.uid,
+                       "groups": info.groups})
+    images = context_image_infos(resource)
+    if images:
+        ctx.add_image_infos(images)
+    return ctx
+
+
+def build_scan_context(
+    policy: ClusterPolicy,
+    resource: Dict[str, Any],
+    namespace_labels: Optional[Dict[str, str]],
+    operation: str = "",
+    admission_info: Optional[RequestInfo] = None,
+) -> PolicyContext:
+    """Background-scan PolicyContext: request.operation stays absent
+    unless a real admission operation exists (the charts' preconditions
+    rely on `request.operation || 'BACKGROUND'`). Match-gating still
+    defaults to CREATE (MatchesResourceDescription's default)."""
+    ctx = scan_json_context(resource, operation, admission_info)
+    info = admission_info or RequestInfo()
+    return PolicyContext(
+        policy=policy,
+        new_resource=resource,
+        admission_info=info,
+        namespace_labels=namespace_labels or {},
+        operation=operation or "CREATE",
+        json_context=ctx,
+    )

@@ -210,19 +210,6 @@ class PolicySetLifecycleManager:
         """Start the compile-ahead worker (idempotent)."""
         if self.worker_running:
             return
-        # the worker's XLA warm scans write through the persistent
-        # compile cache when one is configured (serve --xla-cache-dir /
-        # KYVERNO_TPU_XLA_CACHE_DIR): a process restart then re-warms
-        # from disk in seconds instead of re-paying the full build
-        import os as _os
-
-        if _os.environ.get("KYVERNO_TPU_XLA_CACHE_DIR"):
-            try:
-                from ..tpu.cache import enable_xla_compile_cache
-
-                enable_xla_compile_cache()
-            except Exception:
-                pass  # persistence is an optimization, never a gate
         self._stopped.clear()
         self._wake.set()  # reconcile once immediately (initial compile)
         self._worker = threading.Thread(target=self._run, daemon=True,

@@ -33,6 +33,28 @@ _LAZY = {
 }
 
 
+_device = None
+
+
+def device_info():
+    """What the engine dispatches to: JAX's default platform, its
+    device_kind, the device count, and the seconds the first call took
+    to attach them (serve logs it at start; /debug/state shows it)."""
+    global _device
+    if _device is None:
+        import time
+
+        import jax
+
+        t0 = time.perf_counter()
+        devices = jax.devices()
+        _device = {"platform": devices[0].platform,
+                   "kind": devices[0].device_kind,
+                   "count": len(devices),
+                   "attach_s": time.perf_counter() - t0}
+    return _device
+
+
 def __getattr__(name):
     mod = _LAZY.get(name)
     if mod is None:

@@ -26,10 +26,10 @@ the policy engine:
   encode caps and compiled byte paths shape the rows).
 
 - **enable_xla_compile_cache** — turns on JAX's persistent compilation
-  cache (``jax_compilation_cache_dir``) so ``device_fn`` builds survive
-  process restarts: the lifecycle compile-ahead warm scan and the bench
-  probe pay the multi-minute XLA build once per (program, shape), not
-  once per process.
+  cache so ``device_fn`` builds survive process restarts: serve and the
+  bench pay the XLA build once per (program, shape), not once per
+  process. ``JAX_COMPILATION_CACHE_DIR`` names the directory when set;
+  otherwise it is ``<repo>/.xla_cache``.
 
 Caching is only consulted when the compiled set is *cache eligible*
 (TpuEngine.cache_eligible): no runtime dyn-operand slots (those do real
@@ -448,36 +448,44 @@ def configure(verdict_capacity: Optional[int] = None,
 # ---------------------------------------------------------------------------
 # persistent XLA compilation cache
 
-DEFAULT_XLA_CACHE_DIR = ".xla_cache"
+# anchored on the package, not the working directory: the path is part
+# of the cache's key, so a directory that moves with the cwd never hits
+REPO_XLA_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(
+        __file__)))), ".xla_cache")
 _xla_cache_lock = threading.Lock()
 _xla_cache_dir: Optional[str] = None
 
 
-def enable_xla_compile_cache(cache_dir: Optional[str] = None,
-                             ) -> Optional[str]:
-    """Point JAX's persistent compilation cache at ``cache_dir``
-    (flag --xla-cache-dir / env KYVERNO_TPU_XLA_CACHE_DIR, default
-    ``.xla_cache`` under the working directory). Compiled ``device_fn``
-    programs then survive process restarts: a serve restart or the
-    bench probe warm-starts in seconds instead of re-paying the full
-    XLA build. ``none``/``off``/empty disables. Idempotent; returns
-    the active directory or None when disabled.
+def _xla_cache_enabled() -> bool:
+    import jax
+
+    env = os.environ.get("JAX_ENABLE_COMPILATION_CACHE", "")
+    return (env.lower() not in ("0", "false", "no", "off")
+            and bool(jax.config.jax_enable_compilation_cache))
+
+
+def enable_xla_compile_cache() -> Optional[str]:
+    """Turn on JAX's persistent compilation cache in
+    ``$JAX_COMPILATION_CACHE_DIR`` when that is set (JAX reads it
+    itself; this never names another directory), else in
+    ``<repo>/.xla_cache``. ``JAX_ENABLE_COMPILATION_CACHE=false``
+    disables it. Idempotent; returns the active directory or None when
+    disabled.
 
     An unwritable cache dir (read-only/full disk) NEVER fails a
-    compile: the persistent cache is simply not enabled — one op-log
-    event, the ``xla_cache`` storage surface degrades, and every
-    compile runs warm-start-less but correct. The writability check is
-    a real probe-file write: ``makedirs(exist_ok=True)`` succeeds on an
+    compile: the persistent cache is turned off — one op-log event, the
+    ``xla_cache`` storage surface degrades, and every compile runs
+    warm-start-less but correct. The writability check is a real
+    probe-file write: ``makedirs(exist_ok=True)`` succeeds on an
     existing dir even on a read-only filesystem."""
     from ..resilience import storage as stg
 
     global _xla_cache_dir
-    if cache_dir is None:
-        cache_dir = os.environ.get("KYVERNO_TPU_XLA_CACHE_DIR",
-                                   DEFAULT_XLA_CACHE_DIR)
-    if not cache_dir or cache_dir.lower() in ("none", "off", "disabled"):
+    if not _xla_cache_enabled():
         return None
-    cache_dir = os.path.abspath(cache_dir)
+    cache_dir = os.path.abspath(
+        os.environ.get("JAX_COMPILATION_CACHE_DIR") or REPO_XLA_CACHE_DIR)
     with _xla_cache_lock:
         if _xla_cache_dir == cache_dir:
             return cache_dir
@@ -489,6 +497,7 @@ def enable_xla_compile_cache(cache_dir: Optional[str] = None,
         except OSError:
             # degraded + counted by the shim; announce the single
             # consequence (no warm starts) and keep compiling
+            jax.config.update("jax_enable_compilation_cache", False)
             try:
                 from ..observability.log import global_oplog
 
@@ -497,20 +506,24 @@ def enable_xla_compile_cache(cache_dir: Optional[str] = None,
             except Exception:
                 pass
             return None
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
+        if jax.config.jax_compilation_cache_dir != cache_dir:
+            # equal to the env var when it is set (JAX read it at
+            # import unless it was set later in this process)
+            jax.config.update("jax_compilation_cache_dir", cache_dir)
         # default thresholds skip small/fast programs; a policy set's
-        # device_fn at MIN_BUCKET can compile fast on CPU yet still be
-        # worth persisting (the probe's whole point is a warm start)
-        for knob, value in (
-                ("jax_persistent_cache_min_compile_time_secs", 0.0),
-                ("jax_persistent_cache_min_entry_size_bytes", -1)):
-            try:
-                jax.config.update(knob, value)
-            except Exception:  # knob absent on this jax version
-                pass
+        # device_fn at MIN_BUCKET can compile fast yet still be worth
+        # persisting (a serve restart's whole point is a warm start)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
         _xla_cache_dir = cache_dir
     return cache_dir
 
 
 def xla_cache_dir() -> Optional[str]:
-    return _xla_cache_dir
+    """The directory JAX's persistent compilation cache uses, as JAX's
+    own config says, or None when it is off in this process."""
+    if _xla_cache_dir is None or not _xla_cache_enabled():
+        return None
+    import jax
+
+    return jax.config.jax_compilation_cache_dir

@@ -25,10 +25,10 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..api.policy import ClusterPolicy
-from ..engine.context import Context
 from ..engine.engine import Engine as ScalarEngine
 from ..engine.match import RequestInfo
-from ..engine.policycontext import PolicyContext
+from ..engine.policycontext import (PolicyContext, build_scan_context,
+                                    scan_json_context as _scan_json_context)
 from ..engine.response import EngineResponse
 from ..observability.analytics import (NUM_CLASSES, RuleIdent, class_counts,
                                        global_pattern_cells,
@@ -58,57 +58,6 @@ class DeviceResultError(RuntimeError):
     never silently consumed as truth."""
 
 _STATUS_TO_CODE = {"pass": PASS, "skip": SKIP, "fail": FAIL, "error": ERROR}
-
-
-def _scan_json_context(resource: Dict[str, Any], operation: str = "",
-                       admission_info: Optional[RequestInfo] = None) -> Context:
-    """The JSON context both engines evaluate against for one scanned
-    resource: request.object/namespace/operation/userInfo + images.*
-    (policy_context.go:257)."""
-    ctx = Context()
-    ctx.add_resource(resource)
-    ns = (resource.get("metadata") or {}).get("namespace", "")
-    if ns:
-        ctx.add_namespace(ns)
-    if operation:
-        ctx.add_operation(operation)
-    info = admission_info or RequestInfo()
-    ctx.add_user_info({"username": info.username, "uid": info.uid,
-                       "groups": info.groups})
-    try:
-        from ..images import extract_images
-
-        extracted = extract_images(resource)
-        if extracted:
-            ctx.add_image_infos({
-                group: {key: info_.to_dict() for key, info_ in entries.items()}
-                for group, entries in extracted.items()})
-    except Exception:
-        pass  # malformed image strings must not break context building
-    return ctx
-
-
-def build_scan_context(
-    policy: ClusterPolicy,
-    resource: Dict[str, Any],
-    namespace_labels: Optional[Dict[str, str]],
-    operation: str = "",
-    admission_info: Optional[RequestInfo] = None,
-) -> PolicyContext:
-    """Background-scan PolicyContext: request.operation stays absent
-    unless a real admission operation exists (the charts' preconditions
-    rely on `request.operation || 'BACKGROUND'`). Match-gating still
-    defaults to CREATE (MatchesResourceDescription's default)."""
-    ctx = _scan_json_context(resource, operation, admission_info)
-    info = admission_info or RequestInfo()
-    return PolicyContext(
-        policy=policy,
-        new_resource=resource,
-        admission_info=info,
-        namespace_labels=namespace_labels or {},
-        operation=operation or "CREATE",
-        json_context=ctx,
-    )
 
 
 @dataclass
@@ -872,10 +821,18 @@ class TpuEngine:
         self.breaker.record_failure()
         set_dispatch_path(PATH_SCALAR_FALLBACK)
         global_registry.breaker_fallback.inc({"reason": "error"})
+        error = f"{type(e).__name__}: {e}"
         global_tracer.add_event(
             "breaker_fallback", reason="error", breaker=self.breaker.name,
-            breaker_state=self.breaker.state,
-            error=f"{type(e).__name__}: {e}")
+            breaker_state=self.breaker.state, error=error)
+        # a chip compile or runtime error must be readable where
+        # operators look, not only a fallback count: the scalar oracle
+        # answers this batch bit-identically, so nothing else shows it
+        from ..observability.log import global_oplog
+
+        global_oplog.emit("device_dispatch_failed", level="error",
+                          breaker_state=self.breaker.state,
+                          error=error[:2000])
 
     def guarded_dispatch(self, dispatch_fn, want_shape) -> Optional[np.ndarray]:
         """The ONE breaker-gated dispatch ladder (shared with

@@ -101,7 +101,7 @@ def batch_to_host(rows: RowBatch, meta: MetaBatch) -> Dict[str, Any]:
     """Assemble the flat lane dict on host (numpy views — no copy, no
     transfer). Device placement happens in ONE ``jax.device_put`` on the
     whole dict: per-array ``jnp.asarray`` pays a round-trip per lane
-    over the (possibly tunneled) PCIe/ICI link and was the dominant
+    over the host-to-device link and was the dominant
     scan cost (~30x slower than a single batched put)."""
     out = dict(rows.arrays())
     for k, v in meta.arrays().items():

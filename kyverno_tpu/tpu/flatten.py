@@ -736,7 +736,7 @@ def encode_resources(
 # Vocabulary encoding: row-dedup + device-side gather.
 #
 # The dense RowBatch is ~33KB per resource after padding (256 rows x
-# ~25 lanes + the byte pool) — far more than a tunneled or PCIe H2D
+# ~25 lanes + the byte pool) — far more than the host-to-device
 # link wants to move per tile. But cluster snapshots are massively
 # repetitive at ROW granularity: two Pods of the same shape share
 # almost every (path, value, scope) row. So the transferable form is a

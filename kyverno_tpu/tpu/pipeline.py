@@ -50,6 +50,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 import numpy as np
 
 from ..observability.analytics import (class_counts, global_starvation)
+from ..observability.log import global_oplog
 from ..observability.metrics import global_registry
 from ..observability.profiling import (PHASE_DISPATCH, PHASE_ENCODE,
                                        PHASE_ENCODE_WAIT,
@@ -194,8 +195,11 @@ class PipelinedScanner:
                         batch, n = self.scanner.encode(
                             chunk, namespace_labels, ops)
                 payload: Optional[Any] = (batch, n, None)
-            except Exception:
+            except Exception as e:
                 payload = None  # serial quarantining fallback
+                global_oplog.emit("scan_encode_failed", level="warn",
+                                  chunk=idx, resources=len(chunk),
+                                  error=f"{type(e).__name__}: {e}"[:2000])
             dt = time.perf_counter() - t0
             stats["encode_s"] += dt
             chunk_encode_s[idx] = dt
@@ -405,7 +409,10 @@ class PipelinedScanner:
             t0 = time.perf_counter()
             try:
                 result = eng.scan(chunk, namespace_labels, ops)
-            except Exception:
+            except Exception as e:
+                global_oplog.emit("scan_chunk_failed", level="error",
+                                  chunk=idx, resources=len(chunk),
+                                  error=f"{type(e).__name__}: {e}"[:2000])
                 rules = [(e.policy_name, e.rule_name)
                          for e in eng.cps.rules]
                 result = ScanResult(

@@ -216,22 +216,50 @@ class Handlers:
         cps = compile_policy_set(policies, quarantine=quarantine)
         eng = TpuEngine(cps=cps, exceptions=self.exceptions)
         # with the compile-ahead worker running, "ahead" includes the
-        # XLA build at the smallest shape bucket: one warm scan here
-        # means the first post-swap flush dispatches a ready program
-        # instead of paying the jit on the request path. Bisect PROBE
-        # compiles skip it — those engines are thrown away, and a jit
-        # per probe would dominate the bisect cost.
+        # XLA build at EVERY shape bucket a validate flush can dispatch
+        # (MIN_BUCKET up to the largest flush): a bucket left to the
+        # request path spends a whole request budget on the jit, and a
+        # cold replica's first burst would resolve by failurePolicy.
+        # /readyz waits for this version, so it goes ready only once
+        # admission never compiles. Bisect PROBE compiles skip it —
+        # those engines are thrown away, and a jit per probe would
+        # dominate the bisect cost.
         lifecycle = getattr(self, "lifecycle", None)
         if (lifecycle is not None and lifecycle.worker_running
                 and not lifecycle.probing and cps.device_programs):
             try:
-                # live_n=0: a synthetic warm resource must not count in
+                # live_n=0: synthetic warm resources must not count in
                 # the rule analytics (the accumulator is exact over the
-                # REAL workload)
-                eng.scan([{}], live_n=0)
-            except Exception:
-                pass  # warmup is best-effort; dispatch has its own ladder
+                # REAL workload); uncached, so every bucket dispatches
+                for bucket in self.admission_buckets(eng):
+                    eng._scan_uncached([{}] * bucket, live_n=0)
+                if self.mutate_pipeline is not None and cps.mutate_programs:
+                    for bucket in self.admission_buckets(
+                            eng, self.mutate_pipeline):
+                        eng._triage_uncached([{}] * bucket)
+            except Exception as e:
+                # warmup is best-effort; dispatch has its own ladder
+                from ..observability.log import global_oplog
+
+                global_oplog.emit("warm_scan_failed", level="warn",
+                                  error=f"{type(e).__name__}: {e}"[:2000])
         return eng
+
+    def admission_buckets(self, eng: TpuEngine,
+                          pipeline: Optional[AdmissionPipeline] = None
+                          ) -> List[int]:
+        """The batch shapes an admission path dispatches: the engine's
+        power-of-two buckets up to its largest flush (``pipeline``, else
+        the validate path's)."""
+        if pipeline is None:
+            pipeline = self.pipeline
+        top = eng.bucket_size(pipeline.config.max_batch_size
+                              if pipeline is not None
+                              else self.batcher.max_batch)
+        buckets = [eng.MIN_BUCKET]
+        while buckets[-1] < top:
+            buckets.append(buckets[-1] * 2)
+        return buckets
 
     def _pin_version(self) -> Optional[PolicySetVersion]:
         """Flush-time pin for the serving pipeline: None when no
@@ -803,8 +831,16 @@ class Handlers:
         from ..tpu.cache import (global_encode_cache, global_verdict_cache,
                                  xla_cache_dir)
 
+        from ..native import status as native_status
+        from ..tpu import device_info
+
         state: Dict[str, Any] = {
             "engine_toggle": self.toggles.engine,
+            # where device batches run (None: the scalar engine never
+            # attaches one) and whether the C encoder feeds them
+            "device": device_info() if self.toggles.engine == "tpu"
+            else None,
+            "native_encoder": native_status(),
             "breaker": {"name": breaker.name, "state": breaker.state},
             "compile_cache": compile_cache,
             "perf_caches": {
@@ -1662,6 +1698,14 @@ def handle_debug_path(path: str, handlers: Optional[Handlers] = None
     return 404, b"unknown debug path\n", "text/plain"
 
 
+class HTTPServer(ThreadingHTTPServer):
+    """The API server opens one connection per in-flight review: the
+    stdlib's listen backlog of 5 resets the rest of a burst before a
+    handler thread ever sees them."""
+
+    request_queue_size = 1024
+
+
 class AdmissionServer:
     """ThreadingHTTPServer wrapper with optional TLS."""
 
@@ -1767,7 +1811,7 @@ class AdmissionServer:
                 self.end_headers()
                 self.wfile.write(data)
 
-        self._httpd = ThreadingHTTPServer((host, port), _Req)
+        self._httpd = HTTPServer((host, port), _Req)
         self._ssl_ctx: Optional[ssl.SSLContext] = None
         if certfile:
             ctx = ssl.SSLContext(ssl.PROTOCOL_TLS_SERVER)

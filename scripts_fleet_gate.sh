@@ -101,7 +101,7 @@ with open(pol_file, "w") as f:
     yaml.safe_dump(POLICY, f)
 env = dict(os.environ)
 env.update({"JAX_PLATFORMS": "cpu",
-            "KYVERNO_TPU_XLA_CACHE_DIR": os.path.join(tmp, "xla")})
+            "JAX_COMPILATION_CACHE_DIR": os.path.join(tmp, "xla")})
 fleet = [free_port(), free_port()]
 adm = [free_port(), free_port()]
 met = [free_port(), free_port()]
@@ -286,7 +286,7 @@ try:
                          for j in range(N) if j != i)
         env = dict(os.environ)
         env.update({"JAX_PLATFORMS": "cpu",
-                    "KYVERNO_TPU_XLA_CACHE_DIR": os.path.join(tmp, "xla"),
+                    "JAX_COMPILATION_CACHE_DIR": os.path.join(tmp, "xla"),
                     "KYVERNO_TPU_FAULTS": faults.get(i, "")})
         procs.append(subprocess.Popen(
             [sys.executable, "-m", "kyverno_tpu", "serve", pol_file,

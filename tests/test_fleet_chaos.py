@@ -131,7 +131,7 @@ def test_sigkill_mid_scan_fails_over_with_zero_divergence(tmp_path,
     metrics_ports = [_free_port() for _ in range(3)]
     env = dict(os.environ)
     env.update({"JAX_PLATFORMS": "cpu",
-                "KYVERNO_TPU_XLA_CACHE_DIR": str(xla_cache)})
+                "JAX_COMPILATION_CACHE_DIR": str(xla_cache)})
 
     def boot(i):
         peers = ",".join(f"http://127.0.0.1:{fleet_ports[j]}"

@@ -590,6 +590,10 @@ def test_health_ready_and_debug_endpoints():
         assert state["breaker"]["state"] in ("closed", "open", "half_open")
         assert "pipeline" in state and "queue_depth" in state["pipeline"]
         assert "compile_cache" in state and "phase_breakdown" in state
+        # where device batches run, as JAX reports it (CPU under tests)
+        assert state["device"]["platform"] == "cpu"
+        assert state["device"]["count"] == 8 and state["device"]["kind"]
+        assert state["native_encoder"]["loaded"] in (True, False, None)
     finally:
         srv.stop()
 

@@ -8,6 +8,7 @@ resource set serves >=90% of verdicts from the cache.
 """
 
 import numpy as np
+import pytest
 
 from kyverno_tpu.api.policy import ClusterPolicy
 from kyverno_tpu.observability.metrics import global_registry as reg
@@ -458,12 +459,25 @@ def test_resource_content_hash_stability():
     assert request_digest({}, "", None) != request_digest({}, "CREATE", None)
 
 
-def test_xla_compile_cache_dir_populates(tmp_path):
+def _reset_xla_cache():
+    """Leave the process-global compile-cache config pristine."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    import kyverno_tpu.tpu.cache as cache_mod
+
+    jax.config.update("jax_compilation_cache_dir", None)
+    cache_mod._xla_cache_dir = None
+    cc.reset_cache()
+
+
+def test_xla_compile_cache_dir_populates(tmp_path, monkeypatch):
     import jax
     import jax.numpy as jnp
 
     d = str(tmp_path / "xla")
-    assert enable_xla_compile_cache(d) == d
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", d)
+    assert enable_xla_compile_cache() == d
     try:
         assert jax.config.jax_compilation_cache_dir == d
 
@@ -473,16 +487,83 @@ def test_xla_compile_cache_dir_populates(tmp_path):
 
         f(jnp.arange(8)).block_until_ready()
     finally:
-        # leave the process-global config pristine for other tests
-        import kyverno_tpu.tpu.cache as cache_mod
-
-        jax.config.update("jax_compilation_cache_dir", None)
-        cache_mod._xla_cache_dir = None
+        _reset_xla_cache()
     import os
 
     assert os.path.isdir(d)
 
 
-def test_enable_xla_cache_none_disables():
-    assert enable_xla_compile_cache("none") is None
-    assert enable_xla_compile_cache("") is None
+@pytest.mark.parametrize("value", ["false", "0"])
+def test_enable_xla_cache_off_by_jax_env(monkeypatch, value):
+    monkeypatch.setenv("JAX_ENABLE_COMPILATION_CACHE", value)
+    assert enable_xla_compile_cache() is None
+
+
+def test_xla_cache_default_is_repo_dir_whatever_the_cwd(tmp_path,
+                                                        monkeypatch):
+    import os
+
+    import jax
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.chdir(tmp_path)
+    try:
+        assert enable_xla_compile_cache() == os.path.join(repo, ".xla_cache")
+        assert jax.config.jax_compilation_cache_dir == os.path.join(
+            repo, ".xla_cache")
+    finally:
+        _reset_xla_cache()
+
+
+@pytest.mark.parametrize("env_dir", [True, False],
+                         ids=["env", "repo_default"])
+def test_serve_compile_cache_dir(tmp_path, env_dir):
+    """serve uses $JAX_COMPILATION_CACHE_DIR as JAX sees it, else
+    <repo>/.xla_cache — never a directory relative to its cwd."""
+    import json
+    import os
+    import socket
+    import subprocess
+    import sys
+    import time
+    import urllib.request
+
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = {k: v for k, v in os.environ.items()
+           if k not in ("JAX_COMPILATION_CACHE_DIR",
+                        "JAX_ENABLE_COMPILATION_CACHE")}
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=repo)
+    want = os.path.join(repo, ".xla_cache")
+    if env_dir:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "xla")
+    s = socket.socket()
+    s.bind(("127.0.0.1", 0))
+    port = s.getsockname()[1]
+    s.close()
+    p = subprocess.Popen(
+        [sys.executable, "-m", "kyverno_tpu", "serve",
+         os.path.join(repo, "kyverno_tpu", "policies", "pss",
+                      "disallow-host-path.yaml"),
+         "--engine", "scalar", "--port", "0", "--metrics-port", str(port),
+         "--scan-interval", "9999"],
+        cwd=tmp_path, env=env, stdout=subprocess.DEVNULL,
+        stderr=subprocess.DEVNULL)
+    try:
+        deadline = time.monotonic() + 60
+        while True:
+            assert p.poll() is None, "serve exited at startup"
+            try:
+                with urllib.request.urlopen(
+                        f"http://127.0.0.1:{port}/debug/state",
+                        timeout=5) as r:
+                    state = json.loads(r.read())
+                break
+            except OSError:
+                assert time.monotonic() < deadline, "serve never answered"
+                time.sleep(0.2)
+    finally:
+        p.terminate()
+        p.wait(timeout=30)
+    assert state["perf_caches"]["xla_cache_dir"] == want
+    assert not (tmp_path / ".xla_cache").exists()

@@ -124,7 +124,7 @@ def test_sigkill_mid_fold_recovers_bit_identical(tmp_path, serve_procs):
     xla_cache = tmp_path / "xla"
     base_env = dict(os.environ)
     base_env.update({"JAX_PLATFORMS": "cpu",
-                     "KYVERNO_TPU_XLA_CACHE_DIR": str(xla_cache)})
+                     "JAX_COMPILATION_CACHE_DIR": str(xla_cache)})
     base_env.pop("KYVERNO_TPU_FAULTS", None)
 
     def boot(metrics_port, fold_delay_s=None):

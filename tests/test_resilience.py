@@ -302,15 +302,25 @@ def _mk_engine():
 
 
 def test_tpu_dispatch_fault_trips_breaker_and_verdicts_stay_identical(
-        no_verdict_cache):
+        no_verdict_cache, monkeypatch):
+    from kyverno_tpu.observability.log import global_oplog
+
     eng = _mk_engine()
     eng.breaker.reset(failure_threshold=2, reset_timeout_s=60.0)
     resources = [_pod("a", True), _pod("b", False)]
     want = eng.scan(resources).verdicts.tolist()
 
+    events = []
+    monkeypatch.setattr(global_oplog, "emit",
+                        lambda event, **kw: events.append((event, kw)))
     global_faults.arm("tpu.dispatch", mode="raise", p=1.0)
     assert eng.scan(resources).verdicts.tolist() == want  # failure 1
     assert eng.breaker.state == CLOSED
+    # the device error reaches the op log with its message, not only
+    # the fallback counter
+    failed = [kw for e, kw in events if e == "device_dispatch_failed"]
+    assert failed and failed[0]["level"] == "error"
+    assert "tpu.dispatch" in failed[0]["error"]
     assert eng.scan(resources).verdicts.tolist() == want  # failure 2: trip
     assert eng.breaker.state == OPEN
     # open: the device is not even attempted, yet verdicts are identical

@@ -375,18 +375,27 @@ def test_trace_exporter_degrades_at_birth_and_reopens(tmp_path):
 
 def test_xla_cache_unwritable_dir_disables_persistent_cache(tmp_path,
                                                             monkeypatch):
+    import jax
+
     import kyverno_tpu.tpu.cache as cache_mod
     from kyverno_tpu.observability.log import global_oplog
 
     monkeypatch.setattr(cache_mod, "_xla_cache_dir", None)
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path / "xla"))
     seen = []
     monkeypatch.setattr(global_oplog, "emit",
                         lambda event, **kw: seen.append(event))
     # makedirs(exist_ok=True) succeeds on an existing dir even on a
     # read-only mount — only the probe-file write catches this
     global_faults.arm("storage.write", mode="erofs", match="xla_cache")
-    assert cache_mod.enable_xla_compile_cache(str(tmp_path / "xla")) is None
-    assert cache_mod.xla_cache_dir() is None
+    was = jax.config.jax_enable_compilation_cache
+    try:
+        assert cache_mod.enable_xla_compile_cache() is None
+        assert cache_mod.xla_cache_dir() is None
+        # JAX itself stops using the directory it was given
+        assert jax.config.jax_enable_compilation_cache is False
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
     h = st.storage_health(st.SURFACE_XLA_CACHE)
     assert h.degraded
     assert "xla_cache_disabled" in seen
@@ -564,7 +573,7 @@ def test_ambient_enospc_churn_scan_degrades_heals_bit_identical(
     reports_dir = tmp_path / "reports"
     env = dict(os.environ)
     env.update({"JAX_PLATFORMS": "cpu",
-                "KYVERNO_TPU_XLA_CACHE_DIR": str(tmp_path / "xla"),
+                "JAX_COMPILATION_CACHE_DIR": str(tmp_path / "xla"),
                 # fires on the first 5 matched storage writes (the
                 # first journal append + the next 4 re-probes), then
                 # the disk "recovers" — the heal path needs no disarm
@@ -674,7 +683,7 @@ def test_real_enospc_via_rlimit_fsize_shares_the_injected_path(
     env = dict(os.environ)
     env.update({"JAX_PLATFORMS": "cpu",
                 # persistent XLA cache writes would trip the rlimit too
-                "KYVERNO_TPU_XLA_CACHE_DIR": "none"})
+                "JAX_ENABLE_COMPILATION_CACHE": "false"})
     env.pop("KYVERNO_TPU_FAULTS", None)
     metrics_port = _free_port()
     bootstrap = (

@@ -128,6 +128,30 @@ def test_vocab_dedup_is_effective():
         f"vocab {vb.vocab_size} rows vs {n_rows_total} total — dedup ineffective")
 
 
+def test_native_encoder_keyed_by_source_hash(tmp_path):
+    """The loaded binary is named by a hash of fastencode.c + the build
+    command: an edited source keys a new file, so a stale or copied
+    binary never loads."""
+    import os
+    import shutil
+
+    from kyverno_tpu import native
+
+    if native.load() is None:
+        pytest.skip(f"native toolchain unavailable: {native.status()}")
+    src = os.path.join(os.path.dirname(native.__file__), "fastencode.c")
+    key = native._build_key(src, os.environ.get("CC", "gcc"),
+                            __import__("sysconfig").get_paths()["include"])
+    assert os.path.basename(native.status()["so"]) == \
+        f"_fastencode-{key}.so"
+    edited = tmp_path / "fastencode.c"
+    shutil.copy(src, edited)
+    with open(edited, "a") as f:
+        f.write("\n/* edited */\n")
+    assert native._build_key(str(edited), "gcc", "inc") != \
+        native._build_key(src, "gcc", "inc")
+
+
 def test_native_encoder_parity():
     """The C walk (native/fastencode.c) must agree with the Python
     vocab encoder on densified output, n_rows and fallback for every
