@@ -769,6 +769,11 @@ def run(args: argparse.Namespace) -> int:
         exporter = OTLPJsonFileExporter(args.trace_export)
         global_tracer.add_exporter(exporter)
         global_oplog.emit("trace_export_enabled", path=args.trace_export)
+    # every full collection from here on is a python.gc_full span and
+    # counts in kyverno_python_gc_full_total
+    from ..observability.profiling import global_gc_full
+
+    global_gc_full.install()
     cp = ControlPlane(policies, port=args.port, metrics_port=args.metrics_port,
                       cert=args.cert, key=args.key,
                       configuration=configuration, toggles=toggles,
@@ -817,6 +822,7 @@ def run(args: argparse.Namespace) -> int:
     signal.signal(signal.SIGINT, lambda *a: stop.set())
     stop.wait()
     cp.stop()
+    global_gc_full.uninstall()
     if exporter is not None:
         exporter.close()
     return 0

@@ -193,6 +193,23 @@ class MetricsRegistry:
             "kyverno_tpu_device_dispatch_seconds", "device program wall time")
         self.compile_cache = self.counter(
             "kyverno_tpu_compile_cache_total", "policy-set compiles by outcome")
+        # XLA compiles as they happen (observability/profiling.py's
+        # jax.monitoring listener): a build (source=compile) or a load
+        # from the persistent compile cache (source=cache)
+        self.xla_compiles = self.counter(
+            "kyverno_xla_compiles_total",
+            "XLA executables built or loaded from the persistent cache, by source")
+        self.xla_compile_seconds = self.counter(
+            "kyverno_xla_compile_seconds_total",
+            "seconds spent building or loading XLA executables, by source")
+        # full (generation-2) collections of the interpreter, as the
+        # python.gc_full spans record them (profiling.FullCollections)
+        self.python_gc_full = self.counter(
+            "kyverno_python_gc_full_total",
+            "full (generation 2) garbage collections")
+        self.python_gc_full_seconds = self.counter(
+            "kyverno_python_gc_full_seconds_total",
+            "seconds spent in full (generation 2) garbage collections")
         # content-addressed result caches (tpu/cache.py): per-resource
         # verdict-column and encode-row lookups by outcome, eviction
         # pressure, and live size — the hit RATE is the amortization

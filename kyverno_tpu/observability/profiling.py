@@ -8,19 +8,30 @@ hot paths mark ``encode`` / ``compile`` / ``dispatch`` / ``readback`` /
 --phases``, ``/debug/state``) read the breakdown without re-timing
 anything.
 
+Served admission adds one entry per review and stage
+(``review.read`` ... ``review.respond``, webhooks/server.py), taken
+from the timestamps of the stage's span.
+
 Also here: the thread-local dispatch-path marker (device vs scalar
-fallback — the serving pipeline reads it to name the per-request
-dispatch span honestly) and the one-shot ``jax.profiler`` capture
-latch behind ``KYVERNO_TPU_XLA_TRACE_DIR``.
+fallback — the serving flusher reads it to name its batch span's
+engine honestly), the one-shot ``jax.profiler`` capture latch behind
+``KYVERNO_TPU_XLA_TRACE_DIR``, the profiler session behind
+``/debug/xla/start``/``stop`` that writes the program's spans on the
+device trace's clock, and the process-wide counts of what stalls the
+host without a phase of its own: XLA compiles (a ``jax.monitoring``
+listener) and full garbage collections (a ``gc.callbacks`` hook).
 """
 
 from __future__ import annotations
 
+import collections
+import gc
+import json
 import os
 import threading
 import time
 from contextlib import contextmanager
-from typing import Dict, Optional
+from typing import Any, Deque, Dict, List, Optional, Tuple
 
 PHASE_ENCODE = "encode"
 PHASE_COMPILE = "compile"
@@ -172,3 +183,251 @@ def reset_xla_trace_latch() -> None:
     global _xla_captured
     with _xla_latch_lock:
         _xla_captured = False
+
+
+# -- serve readiness and process age -----------------------------------------
+
+_ready_lock = threading.Lock()
+_serve_ready_at: Optional[float] = None
+_module_loaded_at = time.monotonic()
+
+
+def mark_serve_ready() -> bool:
+    """Record that serve first answered /readyz 200; True only for the
+    first call (the one that logs ``serve_ready``)."""
+    global _serve_ready_at
+    with _ready_lock:
+        if _serve_ready_at is not None:
+            return False
+        _serve_ready_at = time.monotonic()
+        return True
+
+
+def serve_is_ready() -> bool:
+    return _serve_ready_at is not None
+
+
+def process_age_s() -> float:
+    """Seconds since this process started (Linux ``/proc``), else since
+    this module loaded."""
+    try:
+        with open("/proc/self/stat") as f:
+            start_ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        return uptime - start_ticks / os.sysconf("SC_CLK_TCK")
+    except (OSError, IndexError, ValueError):
+        return time.monotonic() - _module_loaded_at
+
+
+# -- XLA compiles, counted where they happen ---------------------------------
+# jax.jit(...) is lazy: the XLA build (or the load from the persistent
+# compile cache) runs inside the first call at each shape. JAX reports
+# it on the calling thread as a duration event; a persistent-cache hit
+# reports its retrieval first, on the same thread.
+
+XLA_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+XLA_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+# before the build, JAX traces the function to a jaxpr and lowers it
+# to an MLIR module, on the host, at every new shape (cache hit or not)
+JAX_TRACE_EVENTS = ("/jax/core/compile/jaxpr_trace_duration",
+                    "/jax/core/compile/jaxpr_to_mlir_module_duration")
+_xla_listener_lock = threading.Lock()
+_xla_listening = False
+
+
+def install_xla_compile_listener() -> None:
+    """Register the compile listener once per process (idempotent)."""
+    global _xla_listening
+    with _xla_listener_lock:
+        if _xla_listening:
+            return
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(_on_xla_duration)
+        _xla_listening = True
+
+
+def thread_xla_seconds() -> Dict[str, float]:
+    """Seconds on this thread so far of JAX tracing and lowering
+    (``trace``), XLA builds (``compile``) and persistent-cache loads
+    (``cache``)."""
+    return dict(_thread_xla_acc())
+
+
+def _thread_xla_acc() -> Dict[str, float]:
+    acc = getattr(_tls, "xla_seconds", None)
+    if acc is None:
+        acc = _tls.xla_seconds = {"trace": 0.0, "compile": 0.0, "cache": 0.0}
+    return acc
+
+
+def _on_xla_duration(event: str, seconds: float, **kwargs: Any) -> None:
+    try:
+        if event in JAX_TRACE_EVENTS:
+            _thread_xla_acc()["trace"] += seconds
+            return
+        if event == XLA_CACHE_LOAD_EVENT:
+            _tls.xla_cache_hit = True
+            return
+        if event != XLA_COMPILE_EVENT:
+            return
+        source = "cache" if getattr(_tls, "xla_cache_hit", False) \
+            else "compile"
+        _tls.xla_cache_hit = False
+        _thread_xla_acc()[source] += seconds
+        from .log import global_oplog
+        from .metrics import global_registry
+        from .tracing import global_tracer
+
+        global_registry.xla_compiles.inc({"source": source})
+        global_registry.xla_compile_seconds.inc({"source": source}, seconds)
+        fun = str(kwargs.get("fun_name", ""))
+        global_tracer.add_event("xla_compile", seconds=seconds,
+                                source=source, fun=fun)
+        global_oplog.emit("xla_compile", seconds=seconds, source=source,
+                          fun=fun, ready=serve_is_ready())
+    except Exception:
+        pass  # accounting must never fail the compile it watches
+
+
+# -- full garbage collections ------------------------------------------------
+
+
+class FullCollections:
+    """``gc.callbacks`` hook: each generation-2 collection becomes a
+    ``python.gc_full`` span on the tracer's clock and feeds
+    ``kyverno_python_gc_full_total``/``_seconds_total``. The callback
+    runs wherever the collector interrupts, possibly inside a lock the
+    tracer or the registry holds, so it only takes its two timestamps
+    and appends; ``drain`` turns the records into spans and counts
+    (on every /metrics scrape and when a profiler session ends)."""
+
+    def __init__(self) -> None:
+        self.pending: Deque[Tuple[float, float, int]] = collections.deque()
+        self._t0 = 0.0
+
+    def __call__(self, phase: str, info: Dict[str, Any]) -> None:
+        if info.get("generation") != 2:
+            return
+        if phase == "start":
+            self._t0 = time.monotonic()
+        else:
+            self.pending.append((self._t0, time.monotonic(),
+                                 info.get("collected", 0)))
+
+    def install(self) -> None:
+        from .metrics import global_registry
+
+        if self not in gc.callbacks:
+            gc.callbacks.append(self)
+        global_registry.add_collect_hook(self.drain)
+
+    def uninstall(self) -> None:
+        if self in gc.callbacks:
+            gc.callbacks.remove(self)
+        self.drain()
+
+    def drain(self) -> None:
+        from .metrics import global_registry
+        from .tracing import global_tracer
+
+        while True:
+            try:
+                t0, t1, freed = self.pending.popleft()
+            except IndexError:
+                return
+            global_tracer.record_span("python.gc_full", t0, t1,
+                                      collected=freed)
+            global_registry.python_gc_full.inc()
+            global_registry.python_gc_full_seconds.inc(value=t1 - t0)
+
+
+global_gc_full = FullCollections()
+
+
+# -- profiler session on the device trace's clock ----------------------------
+
+PROGRAM_SPANS_FILE = "program_spans.json"
+
+
+class XlaSession:
+    """One ``jax.profiler`` session with the program's spans beside it.
+
+    ``start`` opens the trace, then one ``TraceAnnotation`` (the clock
+    anchor) bracketed by ``time.monotonic_ns()`` reads, and attaches an
+    in-memory span exporter. ``stop`` ends the trace, records the
+    profiler's own start and stop as ``debug.xla_start``/``_stop``
+    spans, and writes ``<dir>/program_spans.json`` once. The anchor's
+    ``start_ns`` in the trace's host plane minus the bracket's midpoint
+    maps every span onto the trace's timeline."""
+
+    def __init__(self, out_dir: str) -> None:
+        from .tracing import SpanSession
+
+        self.out_dir = out_dir
+        self.spans = SpanSession()
+        self.anchor_ns: List[int] = []
+        self.started: Tuple[float, float] = (0.0, 0.0)
+
+    def start(self) -> None:
+        import jax
+
+        from .tracing import CLOCK_ANCHOR, global_tracer
+
+        t0 = time.monotonic()
+        jax.profiler.start_trace(self.out_dir)
+        a0 = time.monotonic_ns()
+        with jax.profiler.TraceAnnotation(CLOCK_ANCHOR):
+            pass
+        a1 = time.monotonic_ns()
+        self.anchor_ns = [a0, a1]
+        self.started = (t0, time.monotonic())
+        global_tracer.add_exporter(self.spans)
+
+    def stop(self) -> str:
+        import jax
+
+        from .tracing import global_tracer
+
+        t0 = time.monotonic()
+        try:
+            jax.profiler.stop_trace()
+        finally:
+            t1 = time.monotonic()
+            global_tracer.record_span("debug.xla_start", *self.started,
+                                      dir=self.out_dir)
+            global_tracer.record_span("debug.xla_stop", t0, t1,
+                                      dir=self.out_dir)
+            global_gc_full.drain()
+            global_tracer.remove_exporter(self.spans)
+        path = os.path.join(self.out_dir, PROGRAM_SPANS_FILE)
+        with open(path, "w") as f:
+            json.dump(self.spans.to_dict(self.anchor_ns), f, default=str)
+        return path
+
+
+_xla_session_lock = threading.Lock()
+_xla_session: Optional[XlaSession] = None
+
+
+def start_xla_session(out_dir: str) -> None:
+    """``/debug/xla/start?dir=D``; raises when a session is open or the
+    profiler cannot start."""
+    global _xla_session
+    with _xla_session_lock:
+        if _xla_session is not None:
+            raise RuntimeError("an XLA profiler session is already open")
+        session = XlaSession(out_dir)
+        session.start()
+        _xla_session = session
+
+
+def stop_xla_session() -> str:
+    """``/debug/xla/stop``: the path of the written program spans."""
+    global _xla_session
+    with _xla_session_lock:
+        session, _xla_session = _xla_session, None
+    if session is None:
+        raise RuntimeError("no XLA profiler session is open")
+    return session.stop()

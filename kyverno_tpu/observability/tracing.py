@@ -6,7 +6,8 @@ same causality story with real identifiers: 128-bit trace IDs, 64-bit
 span IDs, and an explicit ``SpanContext`` that crosses thread
 boundaries by value — the serving queue attaches the submitting
 request's context to its pending-request record so the flusher thread's
-queue-wait / flush / dispatch / verdict spans land in the SAME trace,
+queue-wait / flush spans land in the SAME trace (each naming the
+shared ``admission.batch`` span that the engine's spans nest under),
 and ``parallel/sharding.py`` propagates a scan-level context to every
 tile's encode/device/host spans.
 
@@ -19,21 +20,28 @@ Clock discipline: span ``start``/``end`` are ``time.monotonic()``
 (comparable with the serving queue's arrival/deadline stamps, so
 retroactively recorded spans — ``record_span`` — line up with live
 ones); export converts to wall-clock nanoseconds via the tracer's
-monotonic->epoch anchor.
+monotonic->epoch anchor. A profiler session (``SpanSession``) keeps
+monotonic nanoseconds instead, beside a clock-anchor annotation that
+the device trace records on its own clock: the one shared event that
+maps spans onto the trace's timeline.
 """
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 import threading
 import time
 from contextlib import contextmanager
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Deque, Dict, List, Optional
 
 STATUS_OK = "ok"
 STATUS_ERROR = "error"
+# the profiler annotation that puts this tracer's clock on a device
+# trace's timeline (observability/profiling.py start_xla_session)
+CLOCK_ANCHOR = "kyverno.clock_anchor"
 
 
 def new_trace_id() -> str:
@@ -231,6 +239,39 @@ class OTLPJsonFileExporter:
                 pass
 
 
+class SpanSession:
+    """In-memory exporter for one profiler session: every span finished
+    while it is attached, kept for one write when the session ends
+    (``/debug/xla/stop``), so a session costs no file I/O per span."""
+
+    def __init__(self) -> None:
+        self.spans: List[Span] = []
+
+    def __call__(self, span: Span) -> None:
+        self.spans.append(span)
+
+    def to_dict(self, anchor_ns: List[int]) -> Dict[str, Any]:
+        """The ``program_spans.json`` document: the clock anchor's
+        ``time.monotonic_ns()`` bracket and every span, start and end in
+        monotonic nanoseconds (the clock of ``Span.start``/``end``)."""
+        return {
+            "clock": "monotonic_ns",
+            "anchor": {"name": CLOCK_ANCHOR, "mono_ns": list(anchor_ns)},
+            "spans": [{
+                "name": s.name,
+                "trace_id": s.trace_id,
+                "span_id": s.span_id,
+                "parent": s.parent_span_id,
+                "start_ns": int(s.start * 1e9),
+                "end_ns": int(s.end * 1e9),
+                "attributes": dict(s.attributes),
+                **({"events": [[e.name, int(e.timestamp * 1e9),
+                                dict(e.attributes)] for e in s.events]}
+                   if s.events else {}),
+            } for s in self.spans],
+        }
+
+
 class Tracer:
     """Span factory + bounded in-memory store.
 
@@ -245,9 +286,8 @@ class Tracer:
         self._exporters: List[Callable[[Span], None]] = []
         if exporter is not None:
             self._exporters.append(exporter)
-        self._spans: List[Span] = []
+        self._spans: Deque[Span] = collections.deque(maxlen=max_spans)
         self._lock = threading.Lock()
-        self._max = max_spans
         self._local = threading.local()
 
     # -- exporter plumbing
@@ -266,8 +306,6 @@ class Tracer:
     def _export(self, span: Span) -> None:
         with self._lock:
             self._spans.append(span)
-            if len(self._spans) > self._max:
-                self._spans = self._spans[-self._max:]
             exporters = list(self._exporters)
         for exp in exporters:
             try:
@@ -338,6 +376,23 @@ class Tracer:
                     break
             self._export(s)
 
+    @contextmanager
+    def activate(self, span: Span):
+        """Make a ``start_span`` span this thread's current span for the
+        block, so spans opened inside become its children, without
+        ending or exporting it: the caller still owns ``end_span`` (the
+        admission flusher exports its batch span only after every
+        waiter has woken)."""
+        stack = self._stack()
+        stack.append(span)
+        try:
+            yield span
+        finally:
+            for i in range(len(stack) - 1, -1, -1):
+                if stack[i] is span:
+                    del stack[i]
+                    break
+
     def start_span(self, name: str, parent: Optional[SpanContext] = None,
                    **attributes: Any) -> Span:
         """Manual lifecycle for spans that outlive a lexical scope (a
@@ -403,7 +458,7 @@ class Tracer:
     def reset(self) -> None:
         """Drop stored spans (tests); exporters stay attached."""
         with self._lock:
-            self._spans = []
+            self._spans.clear()
 
 
 global_tracer = Tracer()

@@ -24,6 +24,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..api.policy import ClusterPolicy
+from ..observability.profiling import install_xla_compile_listener
 from ..tpu.compiler import CompiledPolicySet, compile_policy_set
 from ..tpu.evaluator import build_program
 from ..tpu.flatten import EncodeConfig, encode_resources_vocab
@@ -110,6 +111,7 @@ class ShardedScanner:
         # input shardings come from the committed arrays put() produces:
         # per-resource lanes shard over the mesh, vocabulary lanes
         # replicate (they are the per-tile "embedding tables")
+        install_xla_compile_listener()
         self._step = jax.jit(
             step,
             out_shardings=(NamedSharding(self.mesh, P(None, self.axes)), repl),

@@ -194,7 +194,8 @@ class AdmissionPipeline:
     # -- caller side
 
     def submit(self, payload: Any, deadline_ms: Optional[float] = None,
-               eval_grace_s: Optional[float] = None, cls: Any = None) -> Any:
+               eval_grace_s: Optional[float] = None, cls: Any = None,
+               stamps: Optional[Dict[str, float]] = None) -> Any:
         """``eval_grace_s`` caps how long a DISPATCHED request may wait
         past its queue budget for the evaluator; callers with a hard
         wall (the webhook's request timeout — the API server hangs up
@@ -202,7 +203,10 @@ class AdmissionPipeline:
         a wedged evaluator resolves per failurePolicy inside it instead
         of holding the connection for the full default grace. ``cls``
         is the request's scheduling class (scheduler.classify_request);
-        unclassified requests ride the default tier."""
+        unclassified requests ride the default tier. A request that a
+        flush answered fills ``stamps`` with the monotonic times it was
+        ``enqueued``, ``drained`` and ``evaluated`` (the caller's stage
+        boundaries; a cache hit or a shed leaves it empty)."""
         if self._stopped:
             raise RuntimeError("admission pipeline is stopped")
         pri = priority_of(cls)
@@ -250,7 +254,8 @@ class AdmissionPipeline:
             if thr > 0 and self._burn() > thr:
                 return self._shed(payload, cls, "burn", root, exemplar, t0)
             try:
-                req = self.queue.put(payload, t0 + budget, now=t0,
+                req = self.queue.put(payload, t0 + budget,
+                                     now=time.monotonic(),
                                      trace_ctx=root.context, cls=cls)
             except QueueFullError as e:
                 return self._shed(payload, cls, e.reason, root, exemplar,
@@ -293,6 +298,10 @@ class AdmissionPipeline:
                 raise req.result
             self.metrics.serving_class_requests.inc(
                 {"class": pri, "outcome": path})
+            if stamps is not None and req.evaluated_at:
+                stamps.update(enqueued=req.enqueued_at,
+                              drained=req.drained_at,
+                              evaluated=req.evaluated_at)
             return req.result
 
     # -- overload ladder (shed) and hedged dispatch
@@ -614,15 +623,23 @@ class AdmissionPipeline:
         # cv, so submit()'s wait has eval_grace_s slack for them)
         if now is None:
             now = time.monotonic()
-        # queue-wait spans materialize HERE, retroactively, in each
-        # request's own trace: the flusher owns the drain timestamp and
-        # the queue entry carried the submit span's context over
-        for req in batch:
-            if req.trace_ctx is not None:
-                global_tracer.record_span(
-                    "admission.queue_wait", req.enqueued_at,
-                    req.drained_at or now, parent=req.trace_ctx,
-                    flush_reason=reason)
+        try:
+            self._process_batch(batch, reason, now, drain_info)
+        finally:
+            # queue-wait spans materialize HERE, retroactively, in each
+            # request's own trace, once the flush has woken its
+            # waiters: the flusher owns the drain timestamp and the
+            # queue entry carried the submit span's context over
+            for req in batch:
+                if req.trace_ctx is not None:
+                    global_tracer.record_span(
+                        "admission.queue_wait", req.enqueued_at,
+                        req.drained_at or now, parent=req.trace_ctx,
+                        flush_reason=reason)
+
+    def _process_batch(self, batch: List[QueuedRequest], reason: str,
+                       now: float,
+                       drain_info: Optional[Dict[str, Any]]) -> None:
         # queue-occupancy attribution: aggregate request-seconds spent
         # queued, scrapeable next to the flusher's own state split
         self.metrics.serving_flusher_seconds.inc(
@@ -704,31 +721,47 @@ class AdmissionPipeline:
         # produce different rows under policy churn
         for req in live:
             req.pin = pin
-        t_eval0 = time.monotonic()
+        # ONE live span for the shared evaluation: the engine's
+        # tpu.encode / tpu.dispatch / tpu.assemble spans nest under it,
+        # and each request's flush span names it. It is exported only
+        # after every waiter is woken.
+        batch_span = global_tracer.start_span(
+            "admission.batch", reason=reason, bucket=bucket,
+            batch_size=len(live),
+            **({} if pin_rev is None else {"policyset_revision": pin_rev}))
+        t_eval0 = batch_span.start
         set_dispatch_path(PATH_DEVICE)  # evaluator overwrites on fallback
         try:
-            # chaos hook: an armed serving.flush fault lands here, so
-            # an injected flush failure takes the SAME path a real
-            # evaluator error takes — every waiter gets the exception
-            # and the webhook layer resolves it per failurePolicy
-            from ..resilience.faults import SITE_SERVING_FLUSH, global_faults
+            with global_tracer.activate(batch_span):
+                # chaos hook: an armed serving.flush fault lands here,
+                # so an injected flush failure takes the SAME path a
+                # real evaluator error takes — every waiter gets the
+                # exception and the webhook layer resolves it per
+                # failurePolicy
+                from ..resilience.faults import (SITE_SERVING_FLUSH,
+                                                 global_faults)
 
-            global_faults.fire(SITE_SERVING_FLUSH)
-            results = (self._fn(padded) if self._version_provider is None
-                       else self._fn(padded, pin))
-            if len(results) < len(live):
-                raise RuntimeError("batch evaluator returned wrong arity")
+                global_faults.fire(SITE_SERVING_FLUSH)
+                results = (self._fn(padded)
+                           if self._version_provider is None
+                           else self._fn(padded, pin))
+                if len(results) < len(live):
+                    raise RuntimeError("batch evaluator returned wrong arity")
         except BaseException as e:  # propagate to every waiter
-            t_eval1 = time.monotonic()
+            t_eval1 = batch_span.end = time.monotonic()
             self.metrics.serving_flusher_seconds.inc(
                 {"state": "evaluate"}, t_eval1 - t_eval0)
+            for req in live:
+                req.evaluated_at = t_eval1
             # a request a hedged scalar dispatch already resolved keeps
             # its (correct) verdict: the evaluator error only reaches
             # waiters the hedge did not rescue
             wins = [req.resolve(e) for req in live]
-            self._record_flush_spans(live, reason, bucket, now, t_eval0,
-                                     t_eval1, error=f"{type(e).__name__}: {e}",
-                                     revision=pin_rev)
+            error = f"{type(e).__name__}: {e}"
+            batch_span.set_status("error", error)
+            self._record_flush_spans(live, now, t_eval1, batch_span,
+                                     error=error)
+            global_tracer.end_span(batch_span)
             for req, won in zip(live, wins):
                 # claim-gated like the success loop: a completed hedge
                 # race owns (and already wrote) this request's record
@@ -741,9 +774,11 @@ class AdmissionPipeline:
                     req.trace_ctx.trace_id if req.trace_ctx else "",
                     {"eval_s": t_eval1 - t_eval0})
             return
-        t_eval1 = time.monotonic()
+        t_eval1 = batch_span.end = time.monotonic()
         self.metrics.serving_flusher_seconds.inc(
             {"state": "evaluate"}, t_eval1 - t_eval0)
+        for req in live:
+            req.evaluated_at = t_eval1
         t_resolve0 = time.monotonic()
         # first-writer-wins: a request whose hedged scalar dispatch
         # landed first keeps the scalar rows (bit-identical by the
@@ -755,14 +790,19 @@ class AdmissionPipeline:
         # span recording (and any exporter I/O it triggers) happens
         # AFTER every waiter is woken: the spans carry explicit
         # timestamps, so ordering costs nothing — doing it first would
-        # tax every request's latency with tracing overhead
-        self._record_flush_spans(live, reason, bucket, now, t_eval0, t_eval1,
-                                 revision=pin_rev)
-        for req in live:
-            if req.trace_ctx is not None:
-                global_tracer.record_span(
-                    "admission.verdict_dispatch", t_resolve0, t_resolve1,
-                    parent=req.trace_ctx, batch_size=len(live))
+        # tax every request's latency with tracing overhead. The batch
+        # span says once how the batch resolved: the engine marks the
+        # device-vs-scalar path in a thread-local this thread reads
+        try:
+            from ..resilience.breaker import tpu_breaker
+
+            breaker_state = tpu_breaker().state
+        except Exception:
+            breaker_state = "unknown"
+        batch_span.attributes.update(engine=last_dispatch_path(),
+                                     breaker=breaker_state)
+        self._record_flush_spans(live, now, t_eval1, batch_span)
+        global_tracer.end_span(batch_span)
         if self._flight is not None:
             # AFTER the waiters are resolved and the spans recorded:
             # the flusher thread still holds the dispatch-path thread-
@@ -789,48 +829,22 @@ class AdmissionPipeline:
                                          - req.enqueued_at),
                      "eval_s": eval_s})
 
-    def _record_flush_spans(self, live: List[QueuedRequest], reason: str,
-                            bucket: int, drained_at: float,
-                            t_eval0: float, t_eval1: float,
-                            error: Optional[str] = None,
-                            revision: Optional[int] = None) -> None:
-        """Per-request flush + dispatch spans: the batch evaluation is
-        shared work, but each request's trace must tell the whole story,
-        so the shared timings are recorded once per participating trace
-        — named by HOW the batch actually resolved (the engine marks the
-        device-vs-scalar path in a thread-local this flusher thread
-        reads back). With ``error`` set (the evaluator raised), the
-        flush span records the failure and no dispatch span is emitted —
-        nothing dispatched."""
-        traced = [r for r in live if r.trace_ctx is not None]
-        if not traced:
-            return
-        rev_attr = {} if revision is None else {"policyset_revision": revision}
-        if error is not None:
-            for req in traced:
+    @staticmethod
+    def _record_flush_spans(live: List[QueuedRequest], drained_at: float,
+                            t_eval1: float, batch_span,
+                            error: Optional[str] = None) -> None:
+        """One ``admission.flush`` span per request, drain to evaluated,
+        in the request's own trace, naming the shared batch span
+        (``batch_span_id``) that carries the flush's reason, bucket,
+        size and revision and whose trace holds the engine's spans."""
+        extra = {} if error is None else {"error": error}
+        for req in live:
+            if req.trace_ctx is not None:
                 global_tracer.record_span(
-                    "admission.flush", req.drained_at or drained_at, t_eval1,
-                    parent=req.trace_ctx, status="error", reason=reason,
-                    batch_size=len(live), bucket=bucket, error=error,
-                    **rev_attr)
-            return
-        path = last_dispatch_path()
-        dispatch_name = ("admission.device_dispatch" if path == PATH_DEVICE
-                         else "admission.scalar_fallback")
-        try:
-            from ..resilience.breaker import tpu_breaker
-
-            breaker_state = tpu_breaker().state
-        except Exception:
-            breaker_state = "unknown"
-        for req in traced:
-            global_tracer.record_span(
-                "admission.flush", req.drained_at or drained_at, t_eval1,
-                parent=req.trace_ctx, reason=reason, batch_size=len(live),
-                bucket=bucket, **rev_attr)
-            global_tracer.record_span(
-                dispatch_name, t_eval0, t_eval1, parent=req.trace_ctx,
-                engine=path, breaker=breaker_state, batch_size=len(live))
+                    "admission.flush", req.drained_at or drained_at,
+                    t_eval1, parent=req.trace_ctx,
+                    status="error" if error is not None else "ok",
+                    batch_span_id=batch_span.span_id, **extra)
 
     # -- introspection
 

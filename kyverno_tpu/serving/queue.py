@@ -56,8 +56,9 @@ PIN_PENDING = object()
 
 class QueuedRequest:
     __slots__ = ("payload", "enqueued_at", "deadline", "event", "result",
-                 "dispatched", "trace_ctx", "drained_at", "cls", "vft",
-                 "pin", "hedged", "winner", "flight_claimed", "_rlock")
+                 "dispatched", "trace_ctx", "drained_at", "evaluated_at",
+                 "cls", "vft", "pin", "hedged", "winner", "flight_claimed",
+                 "_rlock")
 
     def __init__(self, payload: Any, enqueued_at: float, deadline: float,
                  trace_ctx: Any = None, cls: Any = None):
@@ -67,11 +68,15 @@ class QueuedRequest:
         self.event = threading.Event()
         self.result: Any = None
         # the submitting request's SpanContext, carried by VALUE across
-        # the queue handoff so the flusher thread's queue-wait / flush /
-        # dispatch / verdict spans land in the SAME trace as the
-        # submit span (observability/tracing.py)
+        # the queue handoff so the flusher thread's queue-wait / flush
+        # spans land in the SAME trace as the submit span
+        # (observability/tracing.py)
         self.trace_ctx = trace_ctx
         self.drained_at: float = 0.0
+        # when the flush's evaluator returned this entry's batch (set
+        # before the waiter is woken): the boundary between the
+        # request's flush and wake stages
+        self.evaluated_at: float = 0.0
         # set under the queue cv the instant drain() hands this entry
         # to the flusher: submit() only extends its wait past the
         # deadline budget for requests the flusher owns (eval grace),

@@ -55,6 +55,12 @@ def device_info():
     return _device
 
 
+def attach_seconds() -> float:
+    """Seconds the device attach took, or 0.0 where nothing in this
+    process has attached yet (the scalar engine never does)."""
+    return _device["attach_s"] if _device is not None else 0.0
+
+
 def __getattr__(name):
     mod = _LAZY.get(name)
     if mod is None:

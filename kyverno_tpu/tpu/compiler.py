@@ -39,6 +39,7 @@ from .ir import (
     compile_rule,
 )
 from .metadata import MetaConfig
+from ..observability.profiling import install_xla_compile_listener
 # the mutation package only pulls api/engine-level modules — no cycle
 from ..mutation.lowering import (PatchTemplate, lower_mutate_rule,
                                  paths_conflict, rule_read_paths,
@@ -195,6 +196,9 @@ class CompiledPolicySet:
     _fn: Optional[Callable] = field(default=None, repr=False)
     _mutate_fn: Optional[Callable] = field(default=None, repr=False)
     _cache_key: Optional[str] = field(default=None, repr=False)
+    # seconds of the lowering, from its policy_set_compile span (serve
+    # reports it in its serve_ready line)
+    lowering_s: float = field(default=0.0, repr=False)
     _policy_spec_hashes: Optional[List[str]] = field(default=None, repr=False)
 
     @property
@@ -209,21 +213,20 @@ class CompiledPolicySet:
         (evaluator.build_program with_counts). Every lookup is
         attributed on kyverno_tpu_compile_cache_total so the hit/miss
         ratio — the recompilation-churn signal SURVEY §7 warns about —
-        is scrapeable, not inferred from latency spikes."""
+        is scrapeable, not inferred from latency spikes. ``jax.jit`` is
+        lazy: the XLA build happens at the first call at each shape,
+        where the compile listener (observability/profiling.py) counts
+        it."""
         from ..observability.metrics import global_registry
-        from ..observability.profiling import PHASE_COMPILE, global_profiler
-        from ..observability.tracing import global_tracer
 
         if self._fn is None:
             global_registry.compile_cache.inc({"outcome": "miss"})
-            with global_profiler.phase(PHASE_COMPILE), \
-                    global_tracer.span("xla_jit_build",
-                                       programs=len(self.device_programs)):
-                self._fn = jax.jit(
-                    build_program(self.device_programs,
-                                  self.encode_cfg.max_instances,
-                                  with_counts=True, dfa=self.dfa)
-                )
+            install_xla_compile_listener()
+            self._fn = jax.jit(
+                build_program(self.device_programs,
+                              self.encode_cfg.max_instances,
+                              with_counts=True, dfa=self.dfa)
+            )
         else:
             global_registry.compile_cache.inc({"outcome": "hit"})
         return self._fn
@@ -239,18 +242,13 @@ class CompiledPolicySet:
         """The jitted triage batch program over the mutate bank — same
         shape contract as device_fn minus the analytics counts (triage
         rows feed routing, not rule stats)."""
-        from ..observability.profiling import PHASE_COMPILE, global_profiler
-        from ..observability.tracing import global_tracer
-
         if self._mutate_fn is None:
-            with global_profiler.phase(PHASE_COMPILE), \
-                    global_tracer.span("xla_jit_build_mutate",
-                                       programs=len(self.mutate_programs)):
-                self._mutate_fn = jax.jit(
-                    build_program(self.mutate_programs,
-                                  self.encode_cfg.max_instances,
-                                  with_counts=False, dfa=self.dfa)
-                )
+            install_xla_compile_listener()
+            self._mutate_fn = jax.jit(
+                build_program(self.mutate_programs,
+                              self.encode_cfg.max_instances,
+                              with_counts=False, dfa=self.dfa)
+            )
         return self._mutate_fn
 
     def mutate_coverage(self) -> Tuple[int, int]:
@@ -341,9 +339,11 @@ def compile_policy_set(
 
     with global_profiler.phase(PHASE_COMPILE), \
             global_tracer.span("policy_set_compile", policies=len(policies),
-                               quarantined=len(quarantine or ())):
-        return _compile_policy_set(policies, encode_cfg, meta_cfg,
-                                   data_sources, quarantine)
+                               quarantined=len(quarantine or ())) as span:
+        cps = _compile_policy_set(policies, encode_cfg, meta_cfg,
+                                  data_sources, quarantine)
+    cps.lowering_s = span.duration
+    return cps
 
 
 def _compile_policy_set(

@@ -798,10 +798,11 @@ class TpuEngine:
         if last_dispatch_path() == PATH_DEVICE:
             global_starvation.record(busy_s=time.perf_counter() - t_disp0,
                                      starved_s=t_enc)
-        return self.assemble(
-            device_table, resources, namespace_labels, operations,
-            admission_infos, live_n=live_n
-        )
+        with global_tracer.span("tpu.assemble", resources=n):
+            return self.assemble(
+                device_table, resources, namespace_labels, operations,
+                admission_infos, live_n=live_n
+            )
 
     def _breaker_open_fallback(self) -> None:
         from ..observability.metrics import global_registry

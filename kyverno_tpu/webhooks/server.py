@@ -26,6 +26,8 @@ from ..config import Configuration, Toggles
 from ..lifecycle import (PolicySetLifecycleManager, PolicySetUnavailable,
                          PolicySetVersion)
 from ..observability.metrics import MetricsRegistry, global_registry
+from ..observability.profiling import global_profiler
+from ..observability.tracing import global_tracer
 from ..cluster.reports import ReportAggregator, ReportResult
 from ..cluster.snapshot import ClusterSnapshot, resource_uid
 from ..engine.engine import Engine as ScalarEngine
@@ -111,6 +113,9 @@ class Handlers:
         # deadline so an overrun resolves per failurePolicy, not a 500
         self.request_timeout_s = request_timeout_s
         self.scalar = ScalarEngine(exceptions=self.exceptions)
+        # set-up seconds of the newest compiled version (lowering, and
+        # each admission bucket's warm), for the serve_ready line
+        self.setup_stages: Dict[str, Any] = {}
         self._rbac_needed: Dict[int, bool] = {}  # per cache revision
         self._lock = threading.Lock()
         # flight-record support: the flusher stashes its flush's
@@ -225,6 +230,8 @@ class Handlers:
         # those engines are thrown away, and a jit per probe would
         # dominate the bisect cost.
         lifecycle = getattr(self, "lifecycle", None)
+        if lifecycle is not None and not lifecycle.probing:
+            self.setup_stages = {"lowering_s": cps.lowering_s, "warm": []}
         if (lifecycle is not None and lifecycle.worker_running
                 and not lifecycle.probing and cps.device_programs):
             try:
@@ -232,11 +239,15 @@ class Handlers:
                 # the rule analytics (the accumulator is exact over the
                 # REAL workload); uncached, so every bucket dispatches
                 for bucket in self.admission_buckets(eng):
-                    eng._scan_uncached([{}] * bucket, live_n=0)
+                    self._warm_bucket(
+                        "validate", bucket,
+                        lambda b: eng._scan_uncached([{}] * b, live_n=0))
                 if self.mutate_pipeline is not None and cps.mutate_programs:
                     for bucket in self.admission_buckets(
                             eng, self.mutate_pipeline):
-                        eng._triage_uncached([{}] * bucket)
+                        self._warm_bucket(
+                            "mutate", bucket,
+                            lambda b: eng._triage_uncached([{}] * b))
             except Exception as e:
                 # warmup is best-effort; dispatch has its own ladder
                 from ..observability.log import global_oplog
@@ -244,6 +255,31 @@ class Handlers:
                 global_oplog.emit("warm_scan_failed", level="warn",
                                   error=f"{type(e).__name__}: {e}"[:2000])
         return eng
+
+    def _warm_bucket(self, path: str, bucket: int, run) -> None:
+        """One bucket of the compile-ahead warm, as a
+        ``setup.warm_bucket`` span split into JAX's tracing and lowering,
+        the XLA build or persistent-cache load on this thread, and the
+        rest (encode, first execution, assemble); serve's
+        ``serve_ready`` line reports the split."""
+        from ..observability.profiling import thread_xla_seconds
+
+        before = thread_xla_seconds()
+        with global_tracer.span("setup.warm_bucket", path=path,
+                                bucket=bucket) as span:
+            run(bucket)
+            after = thread_xla_seconds()
+            stage = {
+                "path": path, "bucket": bucket,
+                "seconds": time.monotonic() - span.start,
+                "jax_trace_s": after["trace"] - before["trace"],
+                "xla_compile_s": after["compile"] - before["compile"],
+                "xla_cache_load_s": after["cache"] - before["cache"]}
+            stage["execute_s"] = (stage["seconds"] - stage["jax_trace_s"]
+                                  - stage["xla_compile_s"]
+                                  - stage["xla_cache_load_s"])
+            span.attributes.update(stage)
+        self.setup_stages["warm"].append(stage)
 
     def admission_buckets(self, eng: TpuEngine,
                           pipeline: Optional[AdmissionPipeline] = None
@@ -806,14 +842,42 @@ class Handlers:
             pass
         ok = compiled and breaker.state != "open"
         detail["ready"] = ok
+        if ok:
+            self._log_serve_ready()
         return ok, detail
+
+    def _log_serve_ready(self) -> None:
+        """The first time /readyz answers 200 in this process: one op-log
+        line ``serve_ready`` with the seconds since the process started
+        and the set-up stages (lowering, the warm of each admission
+        bucket, device attach, the rest unattributed)."""
+        from ..observability.log import global_oplog
+        from ..observability.profiling import (mark_serve_ready,
+                                               process_age_s)
+        from ..tpu import attach_seconds
+
+        if not mark_serve_ready():
+            return
+        age = process_age_s()
+        stages = self.setup_stages or {}
+        warm = stages.get("warm", [])
+        lowering = stages.get("lowering_s", 0.0)
+        warm_s = sum(w["seconds"] for w in warm)
+        attach = attach_seconds()
+        global_oplog.emit(
+            "serve_ready", seconds=age, lowering_s=lowering, warm_s=warm_s,
+            warm_jax_trace_s=sum(w["jax_trace_s"] for w in warm),
+            warm_xla_compile_s=sum(w["xla_compile_s"] for w in warm),
+            warm_xla_cache_load_s=sum(w["xla_cache_load_s"] for w in warm),
+            warm_execute_s=sum(w["execute_s"] for w in warm),
+            attach_s=attach, unattributed_s=age - lowering - warm_s - attach,
+            buckets=warm)
 
     def debug_state(self) -> Dict[str, Any]:
         """/debug/state: one JSON document answering "what is the
         engine doing RIGHT NOW" — queue depth and bucket occupancy,
         breaker state, compile-cache contents, armed faults, and the
         accumulated per-phase cost split."""
-        from ..observability.profiling import global_profiler
         from ..resilience.breaker import tpu_breaker
         from ..resilience.faults import global_faults
 
@@ -972,6 +1036,48 @@ class Handlers:
 
     def validate(self, review: Dict[str, Any], failure_policy: str = "all",
                  policy_key=None) -> Dict[str, Any]:
+        t0 = time.monotonic()
+        stamps: Dict[str, float] = {}
+        try:
+            return self._validate(review, failure_policy, policy_key, stamps)
+        finally:
+            self._record_review_stages(t0, time.monotonic(), stamps)
+
+    def _record_review_stages(self, t0: float, t_end: float,
+                              stamps: Dict[str, float]) -> None:
+        """Cut validate's wall time ``[t0, t_end]`` into contiguous
+        stages: ``admit`` (to the queue entry), ``queue_wait`` and
+        ``flush`` (timed by the flusher, spans of their own), ``wake``
+        (evaluated to submit returning) and ``decide`` (to the end). A
+        review no flush answered (cache hit, shed, the plain batcher)
+        is ``admit`` then ``decide``; one that returned before the
+        verdicts were back, ``admit`` alone. Each stage is a span under
+        this thread's live span (the ``admission.review`` root) and one
+        ``review.<stage>`` entry of the phase profiler, from the same
+        timestamps."""
+        ret = stamps.get("returned")
+        enq, drained, evaluated = (stamps.get("enqueued"),
+                                   stamps.get("drained"),
+                                   stamps.get("evaluated"))
+        if ret is None:
+            stages = [("admit", t0, t_end)]
+        elif enq and drained and evaluated and \
+                t0 <= enq <= drained <= evaluated <= ret:
+            stages = [("admit", t0, enq), ("wake", evaluated, ret),
+                      ("decide", ret, t_end)]
+            global_profiler.add("review.queue_wait", drained - enq)
+            global_profiler.add("review.flush", evaluated - drained)
+        else:
+            stages = [("admit", t0, ret), ("decide", ret, t_end)]
+        parent = global_tracer.current_context()
+        for name, start, end in stages:
+            global_profiler.add("review." + name, end - start)
+            if parent is not None:
+                global_tracer.record_span("admission." + name, start, end,
+                                          parent=parent)
+
+    def _validate(self, review: Dict[str, Any], failure_policy: str,
+                  policy_key, stamps: Dict[str, float]) -> Dict[str, Any]:
         from ..resilience.retry import Deadline
 
         t0 = time.perf_counter()
@@ -1020,9 +1126,10 @@ class Handlers:
                     payload, deadline_ms=queue_ms,
                     eval_grace_s=min(self.pipeline.config.eval_grace_s,
                                      max(0.0, remaining - queue_ms / 1000.0)),
-                    cls=cls)
+                    cls=cls, stamps=stamps)
             else:
                 verdicts = self.batcher.submit(payload, timeout=remaining)
+            stamps["returned"] = time.monotonic()
         except Exception as e:
             allowed = self._fail_open(failure_policy)
             if not allowed and failure_policy == "all" and \
@@ -1569,8 +1676,6 @@ def handle_debug_path(path: str, handlers: Optional[Handlers] = None
     identically or operators end up debugging the debug endpoints."""
     from urllib.parse import parse_qs, urlparse
 
-    from ..observability.tracing import global_tracer
-
     parsed = urlparse(path)
     route = parsed.path
     query = parse_qs(parsed.query)
@@ -1644,7 +1749,6 @@ def handle_debug_path(path: str, handlers: Optional[Handlers] = None
     if route == "/debug/utilization":
         from ..observability.analytics import global_slo, global_starvation
         from ..observability.metrics import global_registry as _reg
-        from ..observability.profiling import global_profiler
         from ..tpu.cache import global_encode_cache, global_verdict_cache
 
         doc = {
@@ -1679,22 +1783,23 @@ def handle_debug_path(path: str, handlers: Optional[Handlers] = None
                          f"trace={s.trace_id} status={s.status} {attrs}".rstrip())
         return 200, ("\n".join(lines) + "\n").encode(), "text/plain"
     if route.startswith("/debug/xla/start"):
-        import jax
+        from ..observability.profiling import start_xla_session
 
         out_dir = query.get("dir", ["/tmp/kyverno-tpu-xla-trace"])[0]
         try:
-            jax.profiler.start_trace(out_dir)
+            start_xla_session(out_dir)
         except Exception as e:
             return 500, f"profiler start failed: {e}\n".encode(), "text/plain"
         return 200, f"xla trace started -> {out_dir}\n".encode(), "text/plain"
     if route.startswith("/debug/xla/stop"):
-        import jax
+        from ..observability.profiling import stop_xla_session
 
         try:
-            jax.profiler.stop_trace()
+            spans = stop_xla_session()
         except Exception as e:
             return 500, f"profiler stop failed: {e}\n".encode(), "text/plain"
-        return 200, b"xla trace stopped\n", "text/plain"
+        return 200, f"xla trace stopped; program spans -> {spans}\n".encode(), \
+            "text/plain"
     return 404, b"unknown debug path\n", "text/plain"
 
 
@@ -1756,6 +1861,15 @@ class AdmissionServer:
                     self.end_headers()
 
             def do_POST(self):
+                # one trace per review: the stages of a /validate review
+                # are spans under this root (Handlers.validate records
+                # admit .. decide, this handler read and respond)
+                t0 = time.monotonic()
+                with global_tracer.span("admission.review") as root:
+                    root.start = t0
+                    self._post(root)
+
+            def _post(self, root):
                 length = int(self.headers.get("Content-Length", 0))
                 body = self.rfile.read(length)
                 try:
@@ -1764,6 +1878,7 @@ class AdmissionServer:
                     self.send_response(400)
                     self.end_headers()
                     return
+                t_read = time.monotonic()
                 path = self.path.rstrip("/")
                 segs = [s for s in path.split("/") if s]
                 base = segs[0] if segs else ""
@@ -1804,12 +1919,22 @@ class AdmissionServer:
                     self.send_response(404)
                     self.end_headers()
                     return
+                t_respond = time.monotonic()
                 data = json.dumps(out).encode()
                 self.send_response(200)
                 self.send_header("Content-Type", "application/json")
                 self.send_header("Content-Length", str(len(data)))
                 self.end_headers()
                 self.wfile.write(data)
+                t_end = time.monotonic()
+                root.attributes["path"] = base
+                if base == "validate":
+                    for stage, start, end in (("read", root.start, t_read),
+                                              ("respond", t_respond, t_end)):
+                        global_profiler.add("review." + stage, end - start)
+                        global_tracer.record_span(
+                            "admission." + stage, start, end,
+                            parent=root.context)
 
         self._httpd = HTTPServer((host, port), _Req)
         self._ssl_ctx: Optional[ssl.SSLContext] = None
@@ -1843,8 +1968,11 @@ class AdmissionServer:
                                   (bodies, verdicts, path, trace ids)
                                   + recorder/verifier state
         /debug/spans              recent spans, one line each (legacy)
-        /debug/xla/start?dir=D    start the JAX/XLA profiler trace
-        /debug/xla/stop           stop it (trace lands in the dir)
+        /debug/xla/start?dir=D    start the JAX/XLA profiler trace,
+                                  with a clock anchor for the spans
+        /debug/xla/stop           stop it (trace lands in the dir, with
+                                  the session's spans in
+                                  program_spans.json)
         """
         return handle_debug_path(path, self.handlers)
 

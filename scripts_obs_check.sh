@@ -109,15 +109,17 @@ try:
 
     # the armed fault forces every device dispatch to fail -> breaker
     # trips -> batches complete via the scalar ladder; the TRACES must
-    # say so: a scalar_fallback span carrying the breaker state
+    # say so: admission.batch spans on the scalar fallback, carrying
+    # the breaker state
     status, body = get(met, "/debug/traces")
     assert status == 200
     traces = json.loads(body)["traces"]
     fallback_spans = [s for t in traces for s in t["spans"]
-                      if s["name"] == "admission.scalar_fallback"]
-    assert fallback_spans, "no scalar_fallback span traced under faults"
-    assert any("breaker" in s["attributes"] for s in fallback_spans), \
-        "fallback span lacks breaker state"
+                      if s["name"] == "admission.batch"
+                      and s["attributes"].get("engine") == "scalar_fallback"]
+    assert fallback_spans, "no scalar-fallback batch traced under faults"
+    assert all("breaker" in s["attributes"] for s in fallback_spans), \
+        "fallback batch span lacks breaker state"
     state = json.loads(get(met, "/debug/state")[1])
     assert state["breaker"]["state"] in ("open", "half_open", "closed")
     assert state["faults_armed"].get("tpu.dispatch", {}).get("fired", 0) > 0

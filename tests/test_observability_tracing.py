@@ -492,21 +492,25 @@ def test_single_request_yields_one_connected_trace():
     submits = [s for s in global_tracer.finished("admission.submit")]
     root = submits[-1]
     trace = {s.name: s for s in global_tracer.trace(root.trace_id)}
-    # >= 5 connected spans: submit, queue wait, flush, dispatch (device
-    # or scalar fallback), verdict dispatch
-    assert {"admission.submit", "admission.queue_wait", "admission.flush",
-            "admission.verdict_dispatch"} <= set(trace)
-    assert ("admission.device_dispatch" in trace
-            or "admission.scalar_fallback" in trace)
-    assert len(trace) >= 5
+    # the request's own trace: submit, queue wait, flush — the shared
+    # evaluation is ONE admission.batch span the flush names, not a
+    # per-request copy
+    assert set(trace) == {"admission.submit", "admission.queue_wait",
+                          "admission.flush"}
     # every span hangs off the submit root's trace, children point at it
     assert trace["admission.queue_wait"].parent_span_id == root.span_id
-    # queue-wait + dispatch durations fit inside the measured latency
-    dispatch = trace.get("admission.device_dispatch") \
-        or trace["admission.scalar_fallback"]
-    summed = trace["admission.queue_wait"].duration + dispatch.duration
-    assert summed <= latency + 0.05, (summed, latency)
-    assert dispatch.duration >= 0.004  # the sleep is visible
+    assert trace["admission.flush"].parent_span_id == root.span_id
+    batch_id = trace["admission.flush"].attributes["batch_span_id"]
+    batch = [s for s in global_tracer.finished("admission.batch")
+             if s.span_id == batch_id]
+    assert len(batch) == 1 and batch[0].attributes["batch_size"] == 1
+    assert batch[0].attributes["engine"] == "device"
+    # queue wait then flush, contiguous, inside the measured latency;
+    # the batch span covers the evaluation and ends with the flush
+    qw, fl = trace["admission.queue_wait"], trace["admission.flush"]
+    assert qw.end == fl.start and fl.end == batch[0].end
+    assert qw.duration + fl.duration <= latency + 0.05, latency
+    assert batch[0].duration >= 0.004  # the sleep is visible
     # the latency histogram carries the trace id as an exemplar
     text = global_registry.exposition()
     assert "kyverno_serving_request_latency_seconds" in text
@@ -514,9 +518,9 @@ def test_single_request_yields_one_connected_trace():
 
 
 def test_trace_records_scalar_fallback_with_breaker_state():
-    """A batch that fails on the 'device' path (fault at serving.flush
-    would error the flush; here the engine marker is exercised via the
-    dispatch-path thread-local) records a scalar_fallback span."""
+    """A batch the engine resolved on the scalar fallback (marked via the
+    dispatch-path thread-local) says so once, on its admission.batch
+    span, with the breaker state beside it."""
     from kyverno_tpu.observability.profiling import (PATH_SCALAR_FALLBACK,
                                                      set_dispatch_path)
     from kyverno_tpu.observability.tracing import global_tracer
@@ -534,7 +538,10 @@ def test_trace_records_scalar_fallback_with_breaker_state():
         pipeline.stop()
     root = global_tracer.finished("admission.submit")[-1]
     trace = {s.name: s for s in global_tracer.trace(root.trace_id)}
-    fb = trace["admission.scalar_fallback"]
+    assert "admission.scalar_fallback" not in trace
+    batch_id = trace["admission.flush"].attributes["batch_span_id"]
+    fb = [s for s in global_tracer.finished("admission.batch")
+          if s.span_id == batch_id][0]
     assert fb.attributes["engine"] == PATH_SCALAR_FALLBACK
     assert fb.attributes["breaker"] in ("closed", "open", "half_open",
                                         "unknown")
