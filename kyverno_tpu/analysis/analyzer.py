@@ -2,7 +2,7 @@
 
 The analyzer evaluates the synthesized witness corpus (witness.py)
 against the FULL compiled policy set through the batched device path —
-``TpuEngine`` / ``CompiledPolicySet.device_fn`` tiles, the same dispatch
+``TpuEngine`` / ``CompiledPolicySet.packed_fn`` tiles, the same dispatch
 ladder production traffic rides — and classifies inter-policy anomalies
 from the resulting verdict table (the firewall static-analysis taxonomy
 of arXiv:1102.1237, reinterpreted for admission control where every

@@ -193,6 +193,16 @@ class MetricsRegistry:
             "kyverno_tpu_device_dispatch_seconds", "device program wall time")
         self.compile_cache = self.counter(
             "kyverno_tpu_compile_cache_total", "policy-set compiles by outcome")
+        # buffers and bytes TpuEngine's packed dispatches move across the
+        # host<->device link (h2d: one slab per lane dtype and leading
+        # extent, d2h: one result); the pipelined and sharded scans are
+        # not counted
+        self.device_transfers = self.counter(
+            "kyverno_device_transfers_total",
+            "buffers moved between host and device by engine dispatches, by direction")
+        self.device_transfer_bytes = self.counter(
+            "kyverno_device_transfer_bytes_total",
+            "bytes moved between host and device by engine dispatches, by direction")
         # XLA compiles as they happen (observability/profiling.py's
         # jax.monitoring listener): a build (source=compile) or a load
         # from the persistent compile cache (source=cache)

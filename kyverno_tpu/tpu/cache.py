@@ -26,7 +26,7 @@ the policy engine:
   encode caps and compiled byte paths shape the rows).
 
 - **enable_xla_compile_cache** — turns on JAX's persistent compilation
-  cache so ``device_fn`` builds survive process restarts: serve and the
+  cache so ``packed_fn`` builds survive process restarts: serve and the
   bench pay the XLA build once per (program, shape), not once per
   process. ``JAX_COMPILATION_CACHE_DIR`` names the directory when set;
   otherwise it is ``<repo>/.xla_cache``.
@@ -511,7 +511,7 @@ def enable_xla_compile_cache() -> Optional[str]:
             # import unless it was set later in this process)
             jax.config.update("jax_compilation_cache_dir", cache_dir)
         # default thresholds skip small/fast programs; a policy set's
-        # device_fn at MIN_BUCKET can compile fast yet still be worth
+        # packed_fn at MIN_BUCKET can compile fast yet still be worth
         # persisting (a serve restart's whole point is a warm start)
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)

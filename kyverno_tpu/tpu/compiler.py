@@ -22,7 +22,7 @@ from ..api.policy import ClusterPolicy, Rule
 from ..engine.operator import Operator
 from ..utils.wildcard import contains_wildcard
 from .dfa import DfaBank, DfaUnsupported, state_budget
-from .evaluator import build_program
+from .evaluator import build_packed_program
 from .flatten import EncodeConfig, plan_byte_pool
 from .ir import (
     ArrayMapsNode,
@@ -193,7 +193,7 @@ class CompiledPolicySet:
     mutate_entries: List[RuleEntry] = field(default_factory=list)
     mutate_programs: List[RuleProgram] = field(default_factory=list)
     mutate_templates: List[Optional[PatchTemplate]] = field(default_factory=list)
-    _fn: Optional[Callable] = field(default=None, repr=False)
+    _packed_fn: Optional[Callable] = field(default=None, repr=False)
     _mutate_fn: Optional[Callable] = field(default=None, repr=False)
     _cache_key: Optional[str] = field(default=None, repr=False)
     # seconds of the lowering, from its policy_set_compile span (serve
@@ -206,30 +206,33 @@ class CompiledPolicySet:
         """Policy indices owning at least one host-fallback rule."""
         return sorted({e.policy_idx for e in self.rules if e.device_row is None})
 
-    def device_fn(self) -> Callable:
-        """The jitted batch program (compiled lazily, cached),
-        returning (verdict table, per-rule verdict-class counts) — the
-        counts are the device-side rule-analytics reduction
-        (evaluator.build_program with_counts). Every lookup is
-        attributed on kyverno_tpu_compile_cache_total so the hit/miss
-        ratio — the recompilation-churn signal SURVEY §7 warns about —
-        is scrapeable, not inferred from latency spikes. ``jax.jit`` is
+    def packed_fn(self) -> Callable:
+        """The jitted batch program (compiled lazily, cached) behind
+        the packed transfer (evaluator.build_packed_program):
+        fn(buffers, layout) over ``pack_lanes`` output, answering the
+        verdict table with the per-rule verdict-class counts (the
+        device-side rule-analytics reduction) after its columns, as ONE
+        int32 array. ``layout`` is static, so each bucket is its own
+        traced shape. Every lookup is attributed on
+        kyverno_tpu_compile_cache_total so the hit/miss ratio — the
+        recompilation-churn signal SURVEY §7 warns about — is
+        scrapeable, not inferred from latency spikes. ``jax.jit`` is
         lazy: the XLA build happens at the first call at each shape,
         where the compile listener (observability/profiling.py) counts
         it."""
         from ..observability.metrics import global_registry
 
-        if self._fn is None:
+        if self._packed_fn is None:
             global_registry.compile_cache.inc({"outcome": "miss"})
             install_xla_compile_listener()
-            self._fn = jax.jit(
-                build_program(self.device_programs,
-                              self.encode_cfg.max_instances,
-                              with_counts=True, dfa=self.dfa)
-            )
+            self._packed_fn = jax.jit(
+                build_packed_program(self.device_programs,
+                                     self.encode_cfg.max_instances,
+                                     with_counts=True, dfa=self.dfa),
+                static_argnums=1)
         else:
             global_registry.compile_cache.inc({"outcome": "hit"})
-        return self._fn
+        return self._packed_fn
 
     @property
     def mutate_rules(self) -> List[Tuple[str, str]]:
@@ -238,17 +241,17 @@ class CompiledPolicySet:
         coordinator."""
         return [(e.policy_name, e.rule_name) for e in self.mutate_entries]
 
-    def mutate_device_fn(self) -> Callable:
-        """The jitted triage batch program over the mutate bank — same
-        shape contract as device_fn minus the analytics counts (triage
-        rows feed routing, not rule stats)."""
+    def mutate_packed_fn(self) -> Callable:
+        """The jitted triage batch program over the mutate bank — the
+        packed contract of ``packed_fn`` minus the analytics counts
+        (triage rows feed routing, not rule stats): one int32 table."""
         if self._mutate_fn is None:
             install_xla_compile_listener()
             self._mutate_fn = jax.jit(
-                build_program(self.mutate_programs,
-                              self.encode_cfg.max_instances,
-                              with_counts=False, dfa=self.dfa)
-            )
+                build_packed_program(self.mutate_programs,
+                                     self.encode_cfg.max_instances,
+                                     with_counts=False, dfa=self.dfa),
+                static_argnums=1)
         return self._mutate_fn
 
     def mutate_coverage(self) -> Tuple[int, int]:

@@ -43,8 +43,9 @@ from ..devtools import sanitizer as _sanitizer
 from ..resilience.faults import (SITE_MUTATE_TRIAGE, SITE_TPU_DISPATCH,
                                  global_faults)
 from .compiler import CompiledPolicySet, compile_policy_set
-from .evaluator import (CONFIRM, ERROR, FAIL, HOST, NOT_MATCHED, PASS, SKIP,
-                        batch_to_host)
+from .evaluator import (CONFIRM, ERROR, FAIL, HOST, NOT_MATCHED,
+                        NUM_VERDICT_CLASSES, PASS, SKIP, batch_to_host,
+                        pack_lanes)
 from .flatten import EncodeConfig, encode_resources
 from .metadata import MetaConfig, encode_metadata
 
@@ -911,6 +912,36 @@ class TpuEngine:
             self._record_dispatch_failure(e)
             return None
 
+    def _run_packed(self, fn, batch: Dict[str, Any]) -> np.ndarray:
+        """One device call on a host lane dict through a packed entry
+        (CompiledPolicySet.packed_fn, mutate_packed_fn): the lanes cross
+        as one slab per dtype (evaluator.pack_lanes) in ONE put, the answer as one int32
+        array in ONE readback. Dispatch (pack, put, async launch, any
+        XLA compile at this shape) and readback (the blocking D2H) are
+        attributed separately. Both transfers are counted on
+        ``kyverno_device_transfers_total``/``_bytes_total`` and on the
+        active ``tpu.dispatch`` span."""
+        import jax
+
+        from ..observability.metrics import global_registry as reg
+
+        with maybe_xla_trace():
+            with global_profiler.phase(PHASE_DISPATCH):
+                buffers, layout = pack_lanes(batch)
+                out = fn(jax.device_put(buffers), layout)
+            with global_profiler.phase(PHASE_READBACK):
+                out = np.asarray(out)
+        bytes_in = sum(b.nbytes for b in buffers)
+        reg.device_transfers.inc({"direction": "h2d"}, len(buffers))
+        reg.device_transfer_bytes.inc({"direction": "h2d"}, bytes_in)
+        reg.device_transfers.inc({"direction": "d2h"})
+        reg.device_transfer_bytes.inc({"direction": "d2h"}, out.nbytes)
+        span = global_tracer.current_span()
+        if span is not None:
+            span.attributes.update(buffers_in=len(buffers),
+                                   bytes_in=bytes_in, buffers_out=1)
+        return out
+
     def _dispatch(self, batch, padded_n: int,
                   n_live: Optional[int] = None) -> np.ndarray:
         """One device dispatch through the guarded ladder. Any failure
@@ -922,32 +953,24 @@ class TpuEngine:
         if n_live is None:
             n_live = padded_n
 
-        def run():
-            import jax
+        D = len(self.cps.device_programs)
 
-            # one batched H2D put for the whole lane dict — per-lane
-            # transfer pays a link round-trip per array (see batch_to_host).
-            # dispatch (async launch + any XLA compile at this shape) and
-            # readback (the blocking D2H) are attributed separately
-            with maybe_xla_trace():
-                with global_profiler.phase(PHASE_DISPATCH):
-                    out = self.cps.device_fn()(jax.device_put(batch))
-                with global_profiler.phase(PHASE_READBACK):
-                    # tolerate monkeypatched device_fns that still
-                    # return a bare verdict table
-                    if isinstance(out, tuple):
-                        table, counts = np.asarray(out[0]), np.asarray(out[1])
-                    else:
-                        table, counts = np.asarray(out), None
-            if counts is not None and table.ndim == 2:
-                # bucket-pad columns are encoded empties, not workload:
-                # their contribution leaves the analytics counts here
-                counts = counts.astype(np.int64) - class_counts(
-                    table[:, n_live:])
+        def run():
+            out = self._run_packed(self.cps.packed_fn(), batch)
+            # the counts ride after the table's columns: a result of
+            # any other width is refused before it is split
+            want = (D, padded_n + NUM_VERDICT_CLASSES)
+            if out.shape != want:
+                raise DeviceResultError(
+                    f"device returned shape {out.shape}, want {want}")
+            # bucket-pad columns are encoded empties, not workload:
+            # their contribution leaves the analytics counts here
+            table = out[:, :padded_n]
+            counts = out[:, padded_n:].astype(np.int64) - class_counts(
+                table[:, n_live:])
             self.set_pending_counts(counts)
             return table
 
-        D = len(self.cps.device_programs)
         table = self.guarded_dispatch(run, (D, padded_n))
         if table is None:
             return np.full((D, padded_n), HOST, dtype=np.int32)
@@ -1357,15 +1380,9 @@ class TpuEngine:
                 batch = None
             if batch is not None:
                 def run():
-                    import jax
-
                     global_faults.fire(SITE_MUTATE_TRIAGE)
-                    with maybe_xla_trace():
-                        with global_profiler.phase(PHASE_DISPATCH):
-                            out = self.cps.mutate_device_fn()(
-                                jax.device_put(batch))
-                        with global_profiler.phase(PHASE_READBACK):
-                            return np.asarray(out)
+                    return self._run_packed(self.cps.mutate_packed_fn(),
+                                            batch)
 
                 device_table = self.guarded_dispatch(run, (d, padded_n))
         if device_table is not None:

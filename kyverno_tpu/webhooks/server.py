@@ -888,7 +888,7 @@ class Handlers:
             "device_rules": active.engine.coverage()[0],
             "total_rules": active.engine.coverage()[1],
             "dyn_slots": len(active.engine.cps.dyn_slots),
-            "jit_built": active.engine.cps._fn is not None,
+            "jit_built": active.engine.cps._packed_fn is not None,
             "policies": [p.name for p in active.engine.cps.policies],
         }]
         from ..observability.metrics import global_registry as _reg

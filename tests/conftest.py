@@ -13,7 +13,7 @@ if "xla_force_host_platform_device_count" not in _flags:
 # the content-addressed verdict/encode caches (tpu/cache.py) are
 # process-global by design; tests must not see each other's entries
 # (an engine mutated out-of-band — monkeypatched oracle, spied
-# device_fn — shares its content key with the unmutated one)
+# packed_fn — shares its content key with the unmutated one)
 import pytest  # noqa: E402
 
 

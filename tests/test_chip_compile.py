@@ -85,18 +85,58 @@ def _pss():
     return [expand_policy(p) for p in load_pss_policies()]
 
 
-@pytest.mark.parametrize("n", [64, 8192])
-def test_pss_evaluator_compiles_for_v5e(one_chip, n):
-    """The program serve dispatches (TpuEngine -> cps.device_fn()) at
-    an admission bucket and at a scan tile."""
+# (entry, policy set, n) -> memory_analysis(), shared by the compile
+# checks and the memory comparison so each program compiles once
+_COMPILED = {}
+
+
+def _memory(eng, entry, n, sharding, key):
+    """The evaluator of ``eng`` compiled for the described chip at
+    ``n`` resources: ``packed`` is the entry TpuEngine dispatches
+    (cps.packed_fn over pack_lanes' slabs), ``dict`` the same program
+    jitted over the lane dict, the reference the packing must not
+    outgrow."""
+    import jax
+
+    from kyverno_tpu.tpu.evaluator import build_program, pack_lanes
+
+    if (entry, key, n) not in _COMPILED:
+        batch, _, _ = eng.encode(_pods(16))
+        if entry == "packed":
+            buffers, layout = pack_lanes(
+                {k: np.broadcast_to(np.zeros((), v.dtype), (n,) + v.shape[1:])
+                 for k, v in batch.items()})
+            compiled = eng.cps.packed_fn().lower(
+                [jax.ShapeDtypeStruct(b.shape, b.dtype, sharding=sharding)
+                 for b in buffers], layout).compile()
+        else:
+            cps = eng.cps
+            compiled = jax.jit(build_program(
+                cps.device_programs, cps.encode_cfg.max_instances,
+                with_counts=True, dfa=cps.dfa)).lower(
+                    _shapes(batch, n, sharding)).compile()
+        _COMPILED[entry, key, n] = compiled.memory_analysis()
+    return _COMPILED[entry, key, n]
+
+
+@pytest.fixture(scope="module")
+def pss_engine():
     from kyverno_tpu.tpu.engine import TpuEngine
 
     eng = TpuEngine(_pss())
     assert eng.coverage() == (66, 66)
-    batch, _, _ = eng.encode(_pods(16))
-    compiled = eng.cps.device_fn().lower(
-        _shapes(batch, n, one_chip)).compile()
-    mem = compiled.memory_analysis()
+    return eng
+
+
+@pytest.mark.parametrize("entry, n", [
+    pytest.param("packed", 64, id="64"),
+    pytest.param("dict", 8192, id="8192"),
+    pytest.param("packed", 8192, id="packed-8192")])
+def test_pss_evaluator_compiles_for_v5e(one_chip, pss_engine, entry, n):
+    """The PSS evaluator: the entry TpuEngine dispatches
+    (cps.packed_fn()) at the largest admission bucket and at a scan
+    tile, and the lane-dict reference at the tile."""
+    mem = _memory(pss_engine, entry, n, one_chip, "pss")
     used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             + mem.temp_size_in_bytes)
     assert 0 < used < V5E_HBM_BYTES, mem
@@ -135,18 +175,49 @@ def _pattern_policies():
     ]
 
 
-def test_dfa_bank_compiles_for_v5e(one_chip):
-    """The DFA bank (uint8/uint16 tables, the byte-lane lax.scan)
-    inside a pattern-heavy set's evaluator."""
+@pytest.fixture(scope="module")
+def pattern_engine():
     from kyverno_tpu.tpu.engine import TpuEngine
 
     eng = TpuEngine(_pattern_policies())
     dev, total = eng.coverage()
     assert eng.cps.dfa is not None and dev == total
-    batch, _, _ = eng.encode(_pods(16))
-    compiled = eng.cps.device_fn().lower(
-        _shapes(batch, 1024, one_chip)).compile()
-    assert compiled.memory_analysis().temp_size_in_bytes < V5E_HBM_BYTES
+    return eng
+
+
+def test_dfa_bank_compiles_for_v5e(one_chip, pattern_engine):
+    """The DFA bank (uint8/uint16 tables, the byte-lane lax.scan)
+    inside a pattern-heavy set's evaluator (lane-dict reference)."""
+    mem = _memory(pattern_engine, "dict", 1024, one_chip, "pattern")
+    assert mem.temp_size_in_bytes < V5E_HBM_BYTES
+
+
+def test_dfa_bank_packed_entry_compiles_for_v5e(one_chip, pattern_engine):
+    """The same pattern-heavy set through the entry TpuEngine
+    dispatches."""
+    mem = _memory(pattern_engine, "packed", 1024, one_chip, "pattern")
+    used = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    assert 0 < used < V5E_HBM_BYTES, mem
+
+
+@pytest.mark.parametrize("key, n", [pytest.param("pss", 8192, id="pss-8192"),
+                                    pytest.param("pattern", 1024,
+                                                 id="dfa-1024")])
+def test_packed_entry_memory_tracks_dict_entry_for_v5e(
+        request, one_chip, key, n):
+    """The packed entry holds each lane twice at most, as its slab and
+    as the lane the evaluator reads, and otherwise runs the reference's
+    program: its temporaries stay within the reference's, 10% of
+    compiler slack and twice its own arguments (tile padding). Slicing
+    the lanes inside the evaluator's loops instead doubled them (v5e:
+    1.24 GB against 0.52 GB at 1024 PSS resources)."""
+    eng = request.getfixturevalue(f"{key}_engine")
+    packed = _memory(eng, "packed", n, one_chip, key)
+    ref = _memory(eng, "dict", n, one_chip, key)
+    assert packed.temp_size_in_bytes <= (
+        1.1 * ref.temp_size_in_bytes + 2 * packed.argument_size_in_bytes), (
+            packed, ref)
 
 
 def test_sharded_scan_step_compiles_for_4_chips(topo):

@@ -664,7 +664,7 @@ def test_serve_metrics_port_serves_debug_surface():
 
 
 def test_scan_records_phases_and_compile_cache_outcomes(no_verdict_cache):
-    # cache off: the second scan must reach device_fn() for the
+    # cache off: the second scan must reach packed_fn() for the
     # compile-cache "hit" outcome this test asserts — the verdict
     # cache would legitimately answer it without dispatching
     from kyverno_tpu.api.policy import ClusterPolicy

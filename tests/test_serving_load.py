@@ -45,7 +45,7 @@ def test_load_batched_equals_scalar_without_recompile(no_verdict_cache):
     payload = _payload_from_request(reviews[0]["request"])
     for bucket in (16, 32):
         batched._evaluate_padded([payload] + [None] * (bucket - 1))
-    fn = eng.cps.device_fn()
+    fn = eng.cps.packed_fn()
     if not hasattr(fn, "_cache_size"):
         pytest.skip("jax jit cache introspection unavailable")
     compiles_after_warmup = fn._cache_size()

@@ -380,13 +380,14 @@ def test_engine_buckets_batch_shapes(monkeypatch, no_verdict_cache):
     })
     eng = TpuEngine([pol])
     shapes = []
-    real_fn = eng.cps.device_fn()
+    real_fn = eng.cps.packed_fn()
 
-    def spying(batch):
-        shapes.append(batch["norm_hi"].shape[0])
-        return real_fn(batch)
+    def spying(buffers, layout):
+        shapes.append(next(shape[0] for name, _, shape, _ in layout
+                           if name == "norm_hi"))
+        return real_fn(buffers, layout)
 
-    monkeypatch.setattr(eng.cps, "device_fn", lambda: spying)
+    monkeypatch.setattr(eng.cps, "packed_fn", lambda: spying)
 
     def mk(i):
         return {"apiVersion": "v1", "kind": "Pod",
